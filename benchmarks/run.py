@@ -1,6 +1,8 @@
 """Benchmark driver — one module per paper figure/table.
 
-Prints ``name,us_per_call,derived`` CSV rows.
+Prints ``name,us_per_call,derived`` CSV rows.  A suite that raises is
+reported with its traceback, the other suites still run, and the driver
+exits 1.
 
   fig5_engine           real serving engine (CPU, reduced config)
   fig6_routing_overhead optimal vs METRO routing wall-clock
@@ -30,6 +32,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 # make `from benchmarks import ...` (and `repro` without an installed
 # wheel) work when invoked as a script: python benchmarks/run.py puts
@@ -137,11 +140,11 @@ def main() -> None:
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:  # keep the suite running
+        except Exception as e:  # keep the other suites running
+            traceback.print_exc()
             print(f"{key}_ERROR,0,{type(e).__name__}:{e}",
                   file=sys.stdout)
-            if args.check:
-                failures.append(f"{key}: raised {type(e).__name__}")
+            failures.append(f"{key}: raised {type(e).__name__}")
             continue
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -153,7 +156,7 @@ def main() -> None:
                 failures.extend(_check(key, rows, args.fast))
     if failures:
         for f in failures:
-            print(f"# REGRESSION {f}", file=sys.stderr)
+            print(f"# FAILED {f}", file=sys.stderr)
         sys.exit(1)
 
 

@@ -163,8 +163,8 @@ def route(
     """Dispatch on routing algorithm name -> per-(token, k) slot ids."""
     if algo == "metro":
         if use_pallas:
-            from repro.kernels import ops as kops
-            expert_slot = kops.metro_route(
+            from repro.kernels.metro_route import metro_route_pallas
+            expert_slot = metro_route_pallas(
                 token_counts, expert_slots,
                 num_devices=num_devices, slots_per_device=slots_per_device)
         else:

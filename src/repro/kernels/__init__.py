@@ -12,8 +12,22 @@
   flash_decode.py — online-softmax decode attention over bf16/fp8 KV
                     caches (in-register dequant after the block DMA)
 
-ops.py: jitted wrappers (interpret mode read per call from
-REPRO_PALLAS_INTERPRET, default on for CPU; explicit interpret=
-overrides).  ref.py: pure-numpy oracles the tests sweep against.
-README.md here: impl matrix, VMEM sizing rule, dead-tile contract.
+Every kernel takes ``interpret=None``: :func:`interpret_mode` resolves
+it from the backend.  ref.py: pure-numpy oracles the tests sweep
+against.  README.md here: impl matrix, VMEM sizing rule, dead-tile
+contract.
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """The one rule for Pallas execution: compiled on a TPU, the
+    interpreter on any other backend.  An explicit bool overrides it
+    (tests that pin one mode)."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() != "tpu"

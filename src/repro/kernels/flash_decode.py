@@ -23,9 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
 
 _NEG = -1e30
 
@@ -70,7 +68,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(
     jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode_pallas(q, k_cache, v_cache, pos, *, block_s: int = 512,
-                        interpret: bool = True):
+                        interpret=None):
     """q: [B, KV, G, hd]; k/v_cache: [B, KV, S, hd] (bf16 or fp8);
     pos: [B] int32 (positions > pos are masked). Returns [B, KV, G, hd]
     in q.dtype."""
@@ -103,8 +101,8 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, block_s: int = 512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(pos.astype(jnp.int32), q, k_cache, v_cache)
 
@@ -116,6 +114,16 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, block_s: int = 512,
 # never sees (or pays HBM traffic for) another sequence's pages, and no
 # dense [B, S] view is ever materialized.
 # ----------------------------------------------------------------------
+
+
+def _lane_view(pool):
+    """[P, ps, KV, hd] pool -> [P, ps, KV*hd] (a free reshape).  A
+    per-head block of the 4-D pool would be (1, ps, 1, hd), whose
+    last two dims break the chip's (8, 128) block rule; on this view
+    head j is the j-th hd-wide lane block, so the block is (1, ps, hd)
+    and the pool layout every other path uses is unchanged."""
+    p, ps, kv, hd = pool.shape
+    return pool.reshape(p, ps, kv * hd)
 
 
 def _paged_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
@@ -131,8 +139,8 @@ def _paged_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                 # [G, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)              # [ps, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)                    # [ps, hd]
+    v = v_ref[0].astype(jnp.float32)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     offs = pb * page_size + jax.lax.broadcasted_iota(
@@ -169,8 +177,8 @@ def _paged_prefill_kernel(start_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                 # [C*G, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)              # [ps, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)                    # [ps, hd]
+    v = v_ref[0].astype(jnp.float32)
     cg = q.shape[0]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
@@ -202,7 +210,7 @@ def _paged_prefill_kernel(start_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def flash_prefill_paged(q, k_pool, v_pool, start, page_table, *,
-                        window: int = 0, interpret: bool = True):
+                        window: int = 0, interpret=None):
     """Chunked-prefill flash attention over the paged KV pool.
 
     The multi-token sibling of :func:`flash_decode_paged`: one prefill
@@ -236,7 +244,7 @@ def flash_prefill_paged(q, k_pool, v_pool, start, page_table, *,
     qf = q.reshape(b, kv, c * g, hd)
 
     def kv_map(i, j, pb, start, pt):
-        return (jnp.maximum(pt[i, pb], 0), 0, j, 0)
+        return (jnp.maximum(pt[i, pb], 0), 0, j)
 
     out = pl.pallas_call(
         kernel,
@@ -246,8 +254,8 @@ def flash_prefill_paged(q, k_pool, v_pool, start, page_table, *,
             in_specs=[
                 pl.BlockSpec((1, 1, c * g, hd),
                              lambda i, j, pb, start, pt: (i, j, 0, 0)),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
+                pl.BlockSpec((1, ps, hd), kv_map),
+                pl.BlockSpec((1, ps, hd), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, c * g, hd),
                                    lambda i, j, pb, start, pt: (i, j, 0, 0)),
@@ -258,17 +266,17 @@ def flash_prefill_paged(q, k_pool, v_pool, start, page_table, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, c * g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(start.astype(jnp.int32), page_table.astype(jnp.int32),
-      qf, k_pool, v_pool)
+      qf, _lane_view(k_pool), _lane_view(v_pool))
     return out.reshape(b, kv, c, g, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_decode_paged(q, k_pool, v_pool, pos, page_table, *,
-                       interpret: bool = True):
+                       interpret=None):
     """Paged flash decode.  q: [B, KV, G, hd]; k/v_pool:
     [num_pages, page_size, KV, hd] (bf16 or fp8); pos: [B] int32;
     page_table: [B, Pmax] int32 physical page ids (-1 = hole; holes and
@@ -288,7 +296,7 @@ def flash_decode_paged(q, k_pool, v_pool, pos, page_table, *,
                                scale=scale)
 
     def kv_map(i, j, pb, pos, pt):
-        return (jnp.maximum(pt[i, pb], 0), 0, j, 0)
+        return (jnp.maximum(pt[i, pb], 0), 0, j)
 
     return pl.pallas_call(
         kernel,
@@ -298,8 +306,8 @@ def flash_decode_paged(q, k_pool, v_pool, pos, page_table, *,
             in_specs=[
                 pl.BlockSpec((1, 1, g, hd),
                              lambda i, j, pb, pos, pt: (i, j, 0, 0)),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
-                pl.BlockSpec((1, ps, 1, hd), kv_map),
+                pl.BlockSpec((1, ps, hd), kv_map),
+                pl.BlockSpec((1, ps, hd), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, hd),
                                    lambda i, j, pb, pos, pt: (i, j, 0, 0)),
@@ -310,8 +318,8 @@ def flash_decode_paged(q, k_pool, v_pool, pos, page_table, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(pos.astype(jnp.int32), page_table.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q, _lane_view(k_pool), _lane_view(v_pool))

@@ -9,7 +9,7 @@ deterministic order means every device computes the identical routing
 from the all-gathered inputs, so no routing table is ever exchanged.
 
 Inputs (see ref.metro_route_ref for exact semantics):
-  order        [N]    processing order (heavy-first, computed by ops.py)
+  order        [N]    processing order (heavy-first, computed by the wrapper)
   token_counts [N]    T[1..N]
   expert_slots [N, W] candidate replica slots per expert (-1 pad)
 Output:
@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 _BIG = jnp.iinfo(jnp.int32).max
 
@@ -82,7 +84,7 @@ def _kernel(order_ref, counts_ref, slots_ref, out_ref, act_ref, tok_ref,
     jax.jit,
     static_argnames=("num_devices", "slots_per_device", "interpret"))
 def metro_route_pallas(token_counts, expert_slots, *, num_devices: int,
-                       slots_per_device: int, interpret: bool = True):
+                       slots_per_device: int, interpret=None):
     """Greedy routing on the TPU scalar core. Returns expert_slot[N]."""
     n, width = expert_slots.shape
     order = jnp.argsort(-token_counts, stable=True).astype(jnp.int32)
@@ -103,6 +105,6 @@ def metro_route_pallas(token_counts, expert_slots, *, num_devices: int,
             pltpu.SMEM((num_devices,), jnp.int32),
             pltpu.SMEM((num_devices,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(order, token_counts.astype(jnp.int32),
       expert_slots.astype(jnp.int32))

@@ -48,9 +48,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
+
+
+def pick_tile(n: int, want: int) -> int:
+    """Largest divisor of ``n`` that is <= ``want``, preferring lane-
+    aligned (multiple of 128) ones — the chip's compiler refuses a
+    block that is neither aligned nor the whole dimension.  qwen3's
+    ``fe=768`` asked for 512 gets 384; small test widths keep their
+    unaligned divisors (they only run in the interpreter)."""
+    divs = [t for t in range(1, min(want, n) + 1) if n % t == 0]
+    aligned = [t for t in divs if t % 128 == 0]
+    return (aligned or divs)[-1]
 
 
 # ----------------------------------------------------------------------
@@ -73,10 +82,13 @@ def _kernel(tile_group, n_live, x_ref, w_ref, out_ref, acc_ref, *,
         acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
                                 preferred_element_type=jnp.float32)
 
-    @pl.when(ki == k_tiles - 1)
+    @pl.when(live & (ki == k_tiles - 1))
     def _flush():
-        out_ref[...] = jnp.where(live, acc_ref[...],
-                                 0.0).astype(out_ref.dtype)
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    @pl.when(~live & (ki == k_tiles - 1))
+    def _flush_dead():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 def _dma_row(i, nl):
@@ -100,7 +112,7 @@ def _freeze(i, nl, live_idx, frozen_idx):
     static_argnames=("tile_m", "tile_k", "tile_f", "interpret"))
 def grouped_ffn_pallas(x, w, tile_group, *, tile_m: int = 0,
                        tile_k: int = 512, tile_f: int = 512,
-                       interpret: bool = True):
+                       interpret=None):
     """x: [C, d] (C = n_tiles * tile_m, sorted/tile-aligned); w: [S, d, f];
     tile_group: [n_tiles] int32, -1 = dead tile (skipped: no weight DMA,
     no FLOPs, zero output). Returns [C, f] in x.dtype."""
@@ -109,9 +121,8 @@ def grouped_ffn_pallas(x, w, tile_group, *, tile_m: int = 0,
     n_tiles = tile_group.shape[0]
     tile_m = tile_m or c // n_tiles
     assert c == n_tiles * tile_m, (c, n_tiles, tile_m)
-    tile_k = min(tile_k, d)
-    tile_f = min(tile_f, f)
-    assert d % tile_k == 0 and f % tile_f == 0, (d, tile_k, f, tile_f)
+    tile_k = pick_tile(d, tile_k)
+    tile_f = pick_tile(f, tile_f)
     k_tiles = d // tile_k
 
     tile_group = tile_group.astype(jnp.int32)
@@ -146,8 +157,8 @@ def grouped_ffn_pallas(x, w, tile_group, *, tile_m: int = 0,
             scratch_shapes=[pltpu.VMEM((tile_m, tile_f), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((c, f), x.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(tile_group, n_live, x, w)
 
@@ -155,6 +166,20 @@ def grouped_ffn_pallas(x, w, tile_group, *, tile_m: int = 0,
 # ----------------------------------------------------------------------
 # fused one-pass expert FFN: up → act → down, hidden stays in VMEM
 # ----------------------------------------------------------------------
+
+
+def _activate(h, fe: int, gated: bool):
+    """silu(gate) * up (or gelu) of the dtype-cast matmul output ``h``,
+    evaluated in fp32 and rounded once.  Mosaic cannot lower these
+    activations on bf16 vectors (their fp32 constants fail its
+    element-type check), and one rounding is also what XLA's fused
+    two-pass epilogue produces."""
+    hf = h.astype(jnp.float32)
+    if gated:
+        act = jax.nn.silu(hf[:, :fe]) * hf[:, fe:]
+    else:
+        act = jax.nn.gelu(hf)
+    return act.astype(h.dtype)
 
 
 def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
@@ -182,13 +207,8 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
         # activation — the two-pass datapath gates on the dtype-cast
         # matmul output (ragged_dot accumulates f32 internally, then
         # casts), and matching it keeps fused serve token-identical
-        h = h_ref[...].astype(hg_ref.dtype)
-        if gated:
-            g, u = h[:, :fe], h[:, fe:]
-            act = jax.nn.silu(g) * u
-        else:
-            act = jax.nn.gelu(h)
-        hg_ref[...] = act.astype(hg_ref.dtype)
+        hg_ref[...] = _activate(h_ref[...].astype(hg_ref.dtype), fe,
+                                gated)
 
     # ---- down phases: stream w_down, accumulate the output ----------
     @pl.when(live & (j >= k_up))
@@ -199,10 +219,13 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
         acc_ref[...] += jnp.dot(hblk, wd_ref[0],
                                 preferred_element_type=jnp.float32)
 
-    @pl.when(j == k_up + k_dn - 1)
+    @pl.when(live & (j == k_up + k_dn - 1))
     def _flush():
-        out_ref[...] = jnp.where(live, acc_ref[...],
-                                 0.0).astype(out_ref.dtype)
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    @pl.when(~live & (j == k_up + k_dn - 1))
+    def _flush_dead():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 @functools.partial(
@@ -211,7 +234,7 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
                      "interpret"))
 def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
                             tile_m: int = 0, tile_k_up: int = 512,
-                            tile_k_dn: int = 512, interpret: bool = True):
+                            tile_k_dn: int = 512, interpret=None):
     """One-pass expert FFN: out = act(x @ w_up[g]) @ w_down[g] per tile.
 
     x: [C, d] sorted/tile-aligned buffer (C = n_tiles * tile_m);
@@ -231,10 +254,8 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
     n_tiles = tile_group.shape[0]
     tile_m = tile_m or c // n_tiles
     assert c == n_tiles * tile_m, (c, n_tiles, tile_m)
-    tile_k_up = min(tile_k_up, d)
-    tile_k_dn = min(tile_k_dn, fe)
-    assert d % tile_k_up == 0 and fe % tile_k_dn == 0, \
-        (d, tile_k_up, fe, tile_k_dn)
+    tile_k_up = pick_tile(d, tile_k_up)
+    tile_k_dn = pick_tile(fe, tile_k_dn)
     k_up = d // tile_k_up
     k_dn = fe // tile_k_dn
 
@@ -291,8 +312,8 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((c, d), x.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(tile_group, n_live, x, w_up, w_down)
 
@@ -343,12 +364,8 @@ def _paged_kernel(tile_group, n_live, frame_map, x_ref, wu_hbm, wd_hbm,
         # cast before the activation: parity with the two-pass datapath
         # (and fused_expert_ffn_pallas), which gates on the dtype-cast
         # matmul output
-        h = h.astype(out_ref.dtype)
-        if gated:
-            act = jax.nn.silu(h[:, :fe]) * h[:, fe:]
-        else:
-            act = jax.nn.gelu(h)
-        y = jnp.dot(act.astype(out_ref.dtype), wd_buf[slot],
+        act = _activate(h.astype(out_ref.dtype), fe, gated)
+        y = jnp.dot(act, wd_buf[slot],
                     preferred_element_type=jnp.float32)
         out_ref[...] = y.astype(out_ref.dtype)
 
@@ -363,7 +380,7 @@ def _paged_kernel(tile_group, n_live, frame_map, x_ref, wu_hbm, wd_hbm,
 def fused_expert_ffn_paged_pallas(x, wu_pool, wd_pool, frame_map,
                                   tile_group, *, gated: bool,
                                   tile_m: int = 0,
-                                  interpret: bool = True):
+                                  interpret=None):
     """Fused expert FFN reading weights from a paged frame pool.
 
     ``wu_pool``: [F, d, n_up*fe] and ``wd_pool``: [F, fe, d] hold F
@@ -403,6 +420,13 @@ def fused_expert_ffn_paged_pallas(x, wu_pool, wd_pool, frame_map,
     frame_map = frame_map.astype(jnp.int32)
 
     kernel = functools.partial(_paged_kernel, fe=fe, gated=gated)
+    # the two weight rings dominate VMEM (18.9 MB at qwen3-30b-a3b's
+    # d=2048, fe=768 in bf16) and exceed the default scoped limit, so
+    # the limit is raised to what the rings plus the pipelined x/out
+    # blocks need, with headroom for Mosaic's own scratch
+    isz = jnp.dtype(x.dtype).itemsize
+    vmem_bytes = (2 * (d * f_up + fe * d) * isz
+                  + 2 * 2 * tile_m * d * isz + (16 << 20))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -410,8 +434,8 @@ def fused_expert_ffn_paged_pallas(x, wu_pool, wd_pool, frame_map,
             grid=(n_tiles,),
             in_specs=[
                 pl.BlockSpec((tile_m, d), lambda i, tg, nl, fm: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # up-weight pool
-                pl.BlockSpec(memory_space=pltpu.ANY),   # down-weight pool
+                pl.BlockSpec(memory_space=pl.ANY),   # up-weight pool
+                pl.BlockSpec(memory_space=pl.ANY),   # down-weight pool
             ],
             out_specs=pl.BlockSpec((tile_m, d),
                                    lambda i, tg, nl, fm: (i, 0)),
@@ -422,7 +446,8 @@ def fused_expert_ffn_paged_pallas(x, wu_pool, wd_pool, frame_map,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((c, d), x.dtype),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_bytes),
     )(tile_group, n_live, frame_map, x, wu_pool, wd_pool)

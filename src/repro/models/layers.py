@@ -503,8 +503,7 @@ def attention_decode_paged(cfg: ModelConfig, params, x, cache, page_table,
         from repro.kernels.flash_decode import flash_decode_paged
         q4 = q.reshape(b, dims.kv, dims.group, dims.head_dim)
         o = flash_decode_paged(
-            q4, new_cache["k"], new_cache["v"], pos, page_table,
-            interpret=jax.default_backend() != "tpu")
+            q4, new_cache["k"], new_cache["v"], pos, page_table)
         o = o.reshape(b, 1, dims.heads * dims.head_dim)
         return o @ params["wo"], new_cache
 

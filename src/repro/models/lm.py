@@ -14,6 +14,7 @@ Modes:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -34,7 +35,7 @@ from repro.sharding.policy import Dist
 
 
 def _init_layer(cfg: ModelConfig, key, dist: Dist, mixer: str, ffn: str,
-                replica_expert):
+                replica_expert, dtype):
     ks = jax.random.split(key, 4)
     p = {"norm1": L.init_norm(cfg, ks[0])}
     if mixer.startswith("attn"):
@@ -46,17 +47,28 @@ def _init_layer(cfg: ModelConfig, key, dist: Dist, mixer: str, ffn: str,
         p["mlp"] = L.init_mlp(cfg, ks[3])
     elif ffn == "moe":
         p["norm2"] = L.init_norm(cfg, ks[2])
-        p["moe"] = MOE.init_moe(cfg, ks[3], dist, replica_expert)
-    return p
+        p["moe"] = MOE.init_moe(cfg, ks[3], dist, replica_expert, dtype)
+    return cast_params(p, dtype)
 
 
 def init_lm(cfg: ModelConfig, key, dist: Dist,
-            replica_expert: Optional[np.ndarray] = None):
-    """Full parameter pytree (fp32 master). MoE layers need the physical
-    replica layout (replica_expert from the placement)."""
+            replica_expert: Optional[np.ndarray] = None,
+            dtype=jnp.float32):
+    """Full parameter pytree in ``dtype``: fp32 is the training master;
+    serving asks for its compute dtype, and gets the values
+    :func:`cast_params` would make of that master.  MoE layers need the
+    physical replica layout (replica_expert from the placement).
+
+    Blocks are built one at a time, each layer cast to ``dtype`` as it
+    is made, and written into a preallocated stack, so the device never
+    holds more than the stack, one block and one expert tensor's fp32
+    temporaries — a whole-model fp32 tree of a published-width MoE does
+    not fit one chip.  Each large tensor and each block is waited for,
+    so that asynchronous dispatch does not queue (and allocate) the
+    draws of later ones meanwhile."""
     if cfg.family == "encdec":
         from repro.models import encdec
-        return encdec.init_encdec(cfg, key, dist)
+        return cast_params(encdec.init_encdec(cfg, key, dist), dtype)
     kinds = cfg.layer_kinds()
     n_blocks = cfg.num_layers // len(kinds)
     k_emb, k_blocks, k_norm, k_head = jax.random.split(key, 4)
@@ -64,23 +76,36 @@ def init_lm(cfg: ModelConfig, key, dist: Dist,
     params = {}
     # even embeddings-mode archs (VLM stub) keep a token table: prefill
     # consumes precomputed patch embeddings, decode embeds generated text
-    params["embed"] = jax.random.normal(k_emb, (v, d), jnp.float32) * 0.02
+    params["embed"] = jax.block_until_ready((jax.random.normal(
+        k_emb, (v, d), jnp.float32) * 0.02).astype(dtype))
     if not cfg.tie_embeddings:
-        params["unembed"] = jax.random.normal(
-            k_head, (d, v), jnp.float32) / np.sqrt(d)
-    params["final_norm"] = L.init_norm(cfg, k_norm)
-
-    bkeys = jax.random.split(k_blocks, n_blocks)
+        params["unembed"] = jax.block_until_ready((jax.random.normal(
+            k_head, (d, v), jnp.float32) / np.sqrt(d)).astype(dtype))
+    params["final_norm"] = cast_params(L.init_norm(cfg, k_norm), dtype)
 
     def one_block(bk):
         lkeys = jax.random.split(bk, len(kinds))
         return {f"l{i}": _init_layer(cfg, lkeys[i], dist, mixer, ffn,
-                                     replica_expert)
+                                     replica_expert, dtype)
                 for i, (mixer, ffn) in enumerate(kinds)}
 
-    blocks = [one_block(bk) for bk in bkeys]
-    params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+    bkeys = jax.random.split(k_blocks, n_blocks)
+    blocks = jax.tree.map(
+        lambda a: jnp.zeros((n_blocks,) + a.shape, a.dtype),
+        jax.eval_shape(one_block, bkeys[0]))
+    for i in range(n_blocks):
+        blocks = jax.block_until_ready(
+            _set_block(blocks, one_block(bkeys[i]), i))
+    params["blocks"] = blocks
     return params
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_block(stack, block, i):
+    """Write one block into the (donated) stacked tree in place."""
+    return jax.tree.map(
+        lambda s, b: jax.lax.dynamic_update_index_in_dim(s, b, i, 0),
+        stack, block)
 
 
 def build_lm_routing(cfg: ModelConfig, placement: Placement,

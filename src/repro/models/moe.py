@@ -45,17 +45,6 @@ from repro.core import routing as core_routing
 from repro.core.types import Placement
 from repro.sharding.policy import Dist
 
-# jax.shard_map became a top-level API only recently; older releases
-# keep it in jax.experimental with `check_rep` instead of `check_vma`
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
 _INT = jnp.int32
 
 
@@ -66,28 +55,38 @@ _INT = jnp.int32
 
 def init_moe(cfg: ModelConfig, key, dist: Dist, replica_expert: np.ndarray,
              dtype=jnp.float32):
-    """Physical expert weights, slot-major ([R, ...], sharded on R over
-    the EP axis; fe sharded over the data axis)."""
+    """Physical expert weights in ``dtype``, slot-major ([R, ...],
+    sharded on R over the EP axis; fe sharded over the data axis).
+
+    Values are drawn in fp32 and cast before the replica gather, so a
+    bf16 layer equals the cast fp32 one.  Each expert tensor is waited
+    for before the next is drawn: with asynchronous dispatch the device
+    would otherwise hold every fp32 draw of a layer at once, several GB
+    at published widths."""
     d, fe = cfg.d_model, cfg.expert_hidden
     n = cfg.num_experts
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(fe)
     n_up = 2 if cfg.gated_mlp else 1
+    f32 = jnp.float32
     # logical init then physical gather so replicas start identical
-    w_up_l = jax.random.normal(k1, (n, d, n_up, fe), dtype) * s_in
-    w_down_l = jax.random.normal(k2, (n, fe, d), dtype) * s_out
     idx = jnp.asarray(replica_expert)
+
+    def experts(k, shape, scale):
+        return jax.block_until_ready(
+            (jax.random.normal(k, shape, f32) * scale).astype(dtype))[idx]
+
     p = {
-        "w_router": jax.random.normal(k3, (d, n), jnp.float32) * s_in,
-        "w_up": w_up_l[idx],        # [R, d, n_up, fe]
-        "w_down": w_down_l[idx],    # [R, fe, d]
+        "w_router": jax.random.normal(k3, (d, n), f32) * s_in,
+        "w_up": experts(k1, (n, d, n_up, fe), s_in),  # [R, d, n_up, fe]
+        "w_down": experts(k2, (n, fe, d), s_out),     # [R, fe, d]
     }
     if cfg.num_shared_experts:
         f_sh = cfg.num_shared_experts * fe
         k5, k6 = jax.random.split(k4)
         p["shared_up"] = jax.random.normal(
-            k5, (d, n_up, f_sh), dtype) * s_in
-        p["shared_down"] = jax.random.normal(k6, (f_sh, d), dtype) * s_out
+            k5, (d, n_up, f_sh), f32) * s_in
+        p["shared_down"] = jax.random.normal(k6, (f_sh, d), f32) * s_out
     return p
 
 
@@ -251,8 +250,8 @@ def grouped_matmul(x, w, group_pad, tile_group, impl: str):
         sel = jax.nn.one_hot(row_group, s_loc, dtype=x.dtype)
         return jnp.einsum("cs,cd,sdf->cf", sel, x, w)
     if impl == "pallas":
-        from repro.kernels import ops as kops
-        return kops.grouped_ffn_matmul(x, w, tile_group)
+        from repro.kernels.moe_ffn import grouped_ffn_pallas
+        return grouped_ffn_pallas(x, w, tile_group)
     if impl == "fused":
         raise ValueError(
             "impl='fused' is the one-pass up→act→down megakernel — it "
@@ -290,8 +289,8 @@ def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
     xg = jnp.where(row_valid[:, None], x[tok], 0).astype(x.dtype)
 
     if impl == "fused":
-        from repro.kernels import ops as kops
-        y = kops.fused_expert_ffn(
+        from repro.kernels.moe_ffn import fused_expert_ffn_pallas
+        y = fused_expert_ffn_pallas(
             xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
             w_down.astype(x.dtype), tile_group, gated=cfg.gated_mlp)
         y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
@@ -299,8 +298,8 @@ def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
         # the double-buffered paged megakernel, driven here with the
         # identity slot->frame map (all local slots resident in order);
         # the expert-pool bench exercises permuted maps directly
-        from repro.kernels import ops as kops
-        y = kops.fused_expert_ffn_paged(
+        from repro.kernels.moe_ffn import fused_expert_ffn_paged_pallas
+        y = fused_expert_ffn_paged_pallas(
             xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
             w_down.astype(x.dtype), jnp.arange(s_l, dtype=jnp.int32),
             tile_group, gated=cfg.gated_mlp)
@@ -533,7 +532,7 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
                 out = jax.lax.psum(out, ax)
             return out, _reduce_stats(stats, all_axes)
 
-        out, stats = _shard_map(
+        out, stats = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, rv_spec, wup_spec, wdn_spec, P(),
                       shared_spec, P(), P()),
@@ -585,7 +584,7 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
             out = jax.lax.psum(out, gather_axes)
         return out.astype(xb.dtype), _reduce_stats(stats, all_axes)
 
-    out, stats = _shard_map(
+    out, stats = jax.shard_map(
         body_f, mesh=mesh,
         in_specs=(x_spec, rv_spec, wup_spec, wdn_spec, P(), shared_spec,
                   P(), P()),

@@ -45,6 +45,7 @@ import dataclasses
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -109,11 +110,11 @@ class ClusterEngine:
                         "mixed": {}}
         self.replicas: list[ServingEngine] = []
         for _ in range(ccfg.num_replicas):
-            # fresh pytree containers per replica (leaves shared):
-            # rebalance swaps leaves in-place per replica, and replicas
+            # each replica owns its weights: rebalance rewrites the
+            # expert stacks in place (donated buffers), and replicas
             # must be able to hold different physical layouts between
             # cluster windows without aliasing each other
-            p_i = jax.tree.map(lambda a: a, params)
+            p_i = jax.tree.map(jnp.copy, params)
             clock = VirtualClock() if step_cost is not None else None
             self.replicas.append(ServingEngine(
                 cfg, dist, p_i, recfg, routing_table_width,

@@ -18,8 +18,8 @@ together (see serving/README.md for the full diagram):
   * :mod:`repro.serving.executor`   — ``Executor``: the jit cache and
     decode/prefill/chunk/mixed step builders, input packing, the KV
     cache pytree (``kv_dtype``: bf16/fp32/fp8 paged pools), the CoW
-    page copy, and the EPLB placement + routing tables + logical
-    master weights the rebalance loop reshuffles.  No scheduling.
+    page copy, and the EPLB placement + routing tables + compute-dtype
+    weights the rebalance loop reshuffles on the device.  No scheduling.
 
 Below state sits the paged-KV substrate: :mod:`repro.serving.kv`
 (refcounted pages) and :mod:`repro.serving.prefix` (the shared-prefix

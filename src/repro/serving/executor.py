@@ -4,9 +4,9 @@ physical expert-weight substrate (placement, routing tables, reshuffle).
 The executor owns everything that touches jax: the per-shape-signature
 jit cache, the decode/prefill/chunk/mixed step builders, the numpy->jnp
 input packers, the KV cache pytree, and the EPLB placement + routing
-tables + logical master weights the rebalance loop reshuffles.  It
-makes *no* scheduling decisions — the engine façade hands it rows the
-scheduler already picked.
+tables + compute-dtype weights the rebalance loop reshuffles on the
+device.  It makes *no* scheduling decisions — the engine façade hands
+it rows the scheduler already picked.
 
 Step builders close over ``(cfg, dist, ecfg)`` only; params / cache /
 routing enter as call arguments.  Engines built from identical configs
@@ -16,6 +16,7 @@ configs is invalid and the caller's responsibility to avoid.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Optional
 
@@ -44,6 +45,19 @@ KV_DTYPES = {
 }
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _regather_slots(w, src):
+    """Reshuffle a stacked expert tensor ``w`` [n_blocks, R, ...] on the
+    device: slot ``s`` of every layer takes old slot ``src[s]``.  One
+    layer at a time into the donated stack, so the peak is one layer's
+    slot set, not a second copy of every layer's experts."""
+    def layer(i, w):
+        return jax.lax.dynamic_update_index_in_dim(
+            w, jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False)[src],
+            i, 0)
+    return jax.lax.fori_loop(0, w.shape[0], layer, w)
+
+
 class Executor:
     def __init__(self, cfg: ModelConfig, dist: Dist, ecfg, params, slo,
                  routing_table_width: int = 0,
@@ -51,7 +65,10 @@ class Executor:
         self.cfg = cfg
         self.dist = dist
         self.ecfg = ecfg
-        self.params = params
+        # weights are held in the compute dtype, cast once here (the
+        # step's own cast_params is then the identity).  The executor
+        # owns them: rebalance rewrites the expert stacks in place.
+        self.params = LM.cast_params(params)
         self.slo = slo
         self._table_width = routing_table_width
 
@@ -66,8 +83,6 @@ class Executor:
                                         self.placement.max_replicas)
             self.routing = LM.build_lm_routing(cfg, self.placement,
                                                self._table_width)
-            # logical master weights (for rebalance reshuffling)
-            self._logical = self._extract_logical(params)
         else:
             self.placement, self.routing = None, {}
 
@@ -100,22 +115,6 @@ class Executor:
     # ------------------------------------------------------------------
     # weight reshuffling (EPLB rebalance)
     # ------------------------------------------------------------------
-    def _extract_logical(self, params):
-        """Logical expert master: replica 0 of each expert."""
-        first_slot = np.array([
-            self.placement.expert_slots[e, 0]
-            for e in range(self.cfg.num_experts)])
-        out = {}
-
-        def grab(tree, path=()):
-            for k, v in tree.items():
-                if isinstance(v, dict):
-                    grab(v, path + (k,))
-                elif k in ("w_up", "w_down") and v.ndim >= 4:
-                    out[path + (k,)] = np.asarray(v)[:, first_slot]
-        grab(params["blocks"])
-        return out
-
     def rebalance(self, loads: np.ndarray,
                   placement=None):
         """Install a new EPLB placement (recomputed from ``loads``
@@ -136,17 +135,20 @@ class Executor:
                 np.asarray(self.placement.replica_expert)
                 != np.asarray(placement.replica_expert))[0]
             self.expert_pool.invalidate_slots(changed)
+        # every expert keeps a replica, so each new slot copies its
+        # expert's weights from one of the slots that hold it now
+        src = jnp.asarray(self.placement.expert_slots[
+            placement.replica_expert, 0], jnp.int32)
         self.placement = placement
         self.routing = LM.build_lm_routing(self.cfg, placement,
                                            self._table_width)
-        idx = placement.replica_expert
 
-        def put(tree, path=()):
-            for k, v in list(tree.items()):
+        def put(tree):
+            for k, v in tree.items():
                 if isinstance(v, dict):
-                    put(v, path + (k,))
+                    put(v)
                 elif k in ("w_up", "w_down") and v.ndim >= 4:
-                    tree[k] = jnp.asarray(self._logical[path + (k,)][:, idx])
+                    tree[k] = _regather_slots(v, src)
         put(self.params["blocks"])
 
     # ------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Fused expert-FFN megakernel: parity sweeps, dead-tile skip contract,
-HBM-traffic/DMA accounting, per-call interpret-mode selection, and the
+HBM-traffic/DMA accounting, backend-derived interpret mode, and the
 engine-level moe_impl="fused" serve equivalence.
 
 The fused kernel (kernels/moe_ffn.fused_expert_ffn_pallas) runs
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops as kops
+from repro.kernels import interpret_mode
 from repro.kernels import ref
 from repro.kernels.moe_ffn import fused_expert_ffn_pallas, grouped_ffn_pallas
 from repro.models.moe import build_pair_buffer, grouped_matmul
@@ -298,24 +298,27 @@ class TestTrafficAndDmaModel:
 
 class TestOpsInterpretPerCall:
     def test_env_read_per_call(self, monkeypatch):
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-        assert kops._interpret() is True
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-        assert kops._interpret() is False
-        monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-        assert kops._interpret() is True
+        """The mode follows the backend, read per call: interpreter off
+        the TPU, compiled on it."""
+        assert interpret_mode() is (jax.default_backend() != "tpu")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert interpret_mode() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert interpret_mode() is True
 
     def test_explicit_override_beats_env(self, monkeypatch):
-        """interpret=True must work even with the env var demanding
-        compiled mode (no TPU here: compiled mode would fail)."""
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-        assert kops._interpret(True) is True
+        """An explicit ``interpret=`` beats the backend rule, and the
+        kernels resolve their default through the same rule: with the
+        backend reported as a TPU, interpret=True still runs (no TPU
+        here: compiled mode would fail)."""
+        assert interpret_mode(True) is True
+        assert interpret_mode(False) is False
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(2, 8, 8)) * 0.2, jnp.float32)
         tg = jnp.asarray([0, 1], jnp.int32)
-        out = np.asarray(kops.grouped_ffn_matmul(x, w, tg,
-                                                 interpret=True))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        out = np.asarray(grouped_ffn_pallas(x, w, tg, interpret=True))
         want = ref.grouped_matmul_ref(np.asarray(x), np.asarray(w),
                                       np.asarray(tg))
         np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
