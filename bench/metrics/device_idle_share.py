@@ -1,0 +1,10 @@
+"""Device: 1 - (union of device operation intervals) / traced window,
+in %."""
+from bench import trace as T
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    lo, hi = run.trace_window
+    return 100.0 * (1.0 - T.busy_ns(run.trace, lo, hi) / (hi - lo))

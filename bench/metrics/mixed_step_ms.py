@@ -1,0 +1,7 @@
+"""Executor: mean synced wall time of the window's steps that carry a
+prefill chunk (mixed and chunk-only steps), in ms."""
+
+
+def read(run):
+    w = [r.wall for r in run.steps if r.kind != "decode"]
+    return sum(w) / len(w) * 1e3 if w else None
