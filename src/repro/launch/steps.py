@@ -19,6 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.configs.shapes import ShapeCell
 from repro.core import build_placement, slots_for_ratio
 from repro.models import lm as LM
+from repro.models import scopes as SC
 from repro.sharding.policy import Dist, param_pspecs
 from repro.training.optimizer import AdamWConfig, adamw_init, adamw_update
 
@@ -182,12 +183,14 @@ def train_shardings(sc: StepConfig, params_shape, opt_shape, batch_specs):
 def make_serve_step(sc: StepConfig, *, greedy: bool = True):
     cfg, dist = sc.cfg, sc.dist
 
+    @jax.named_scope(SC.SERVE_STEP)
     def serve_step(params, tokens, pos, cache, routing):
         logits, new_cache, stats = LM.apply_lm(
             cfg, dist, params, tokens=tokens, pos=pos, cache=cache,
             routing=routing, mode="decode", algo=sc.algo_decode,
             moe_impl=sc.moe_impl)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope(SC.HEAD):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok, new_cache, stats
 
     return serve_step
@@ -196,13 +199,15 @@ def make_serve_step(sc: StepConfig, *, greedy: bool = True):
 def make_prefill_step(sc: StepConfig):
     cfg, dist = sc.cfg, sc.dist
 
+    @jax.named_scope(SC.PREFILL_STEP)
     def prefill_step(params, batch, cache, routing):
         logits, new_cache, stats = LM.apply_lm(
             cfg, dist, params, tokens=batch.get("tokens"),
             embeds=batch.get("embeds"), frames=batch.get("frames"),
             cache=cache, routing=routing, mode="prefill",
             algo=sc.algo_train, moe_impl=sc.moe_impl, chunk=sc.attn_chunk)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope(SC.HEAD):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok, new_cache, stats
 
     return prefill_step

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models import scopes as SC
 
 # ----------------------------------------------------------------------
 # norms
@@ -403,21 +404,22 @@ def attention_prefill_paged(cfg: ModelConfig, params, x, cache, page_table,
 
     # scatter this chunk's K/V through the page table (flat token view;
     # padding tokens and unmapped pages -> OOB index -> dropped)
-    k_tok = k[:, :, 0].transpose(0, 2, 1, 3)                   # [B, C, KV, hd]
-    v_tok = v[:, :, 0].transpose(0, 2, 1, 3)
-    lp = jnp.minimum(positions // ps, pmax - 1)
-    phys = jnp.take_along_axis(page_table, lp, axis=1)         # [B, C]
-    valid_w = (offs[None, :] < n_tok[:, None]) & (phys >= 0)
-    flat_idx = jnp.where(valid_w, phys * ps + positions % ps,
-                         num_pages * ps)
-    kf = cache["k"].reshape(num_pages * ps, kvh, hd)
-    vf = cache["v"].reshape(num_pages * ps, kvh, hd)
-    kf = kf.at[flat_idx.reshape(-1)].set(
-        k_tok.reshape(-1, kvh, hd).astype(kf.dtype), mode="drop")
-    vf = vf.at[flat_idx.reshape(-1)].set(
-        v_tok.reshape(-1, kvh, hd).astype(vf.dtype), mode="drop")
-    new_cache = {"k": kf.reshape(num_pages, ps, kvh, hd),
-                 "v": vf.reshape(num_pages, ps, kvh, hd)}
+    with jax.named_scope(SC.KV_WRITE):
+        k_tok = k[:, :, 0].transpose(0, 2, 1, 3)           # [B, C, KV, hd]
+        v_tok = v[:, :, 0].transpose(0, 2, 1, 3)
+        lp = jnp.minimum(positions // ps, pmax - 1)
+        phys = jnp.take_along_axis(page_table, lp, axis=1)     # [B, C]
+        valid_w = (offs[None, :] < n_tok[:, None]) & (phys >= 0)
+        flat_idx = jnp.where(valid_w, phys * ps + positions % ps,
+                             num_pages * ps)
+        kf = cache["k"].reshape(num_pages * ps, kvh, hd)
+        vf = cache["v"].reshape(num_pages * ps, kvh, hd)
+        kf = kf.at[flat_idx.reshape(-1)].set(
+            k_tok.reshape(-1, kvh, hd).astype(kf.dtype), mode="drop")
+        vf = vf.at[flat_idx.reshape(-1)].set(
+            v_tok.reshape(-1, kvh, hd).astype(vf.dtype), mode="drop")
+        new_cache = {"k": kf.reshape(num_pages, ps, kvh, hd),
+                     "v": vf.reshape(num_pages, ps, kvh, hd)}
 
     # gather this batch's pages and attend with a chunk-offset query
     # window (the Pallas twin is kernels/flash_decode.flash_prefill_paged)
@@ -489,15 +491,17 @@ def attention_decode_paged(cfg: ModelConfig, params, x, cache, page_table,
 
     # write the new token through the page table (1-scatter on the flat
     # token view; unmapped pages -> OOB index -> dropped)
-    lp = jnp.minimum(pos // ps, pmax - 1)
-    phys = page_table[jnp.arange(b), lp]                       # [B]
-    flat_idx = jnp.where(phys >= 0, phys * ps + pos % ps, num_pages * ps)
-    kf = cache["k"].reshape(num_pages * ps, kvh, hd)
-    vf = cache["v"].reshape(num_pages * ps, kvh, hd)
-    kf = kf.at[flat_idx].set(k[:, 0].astype(kf.dtype), mode="drop")
-    vf = vf.at[flat_idx].set(v[:, 0].astype(vf.dtype), mode="drop")
-    new_cache = {"k": kf.reshape(num_pages, ps, kvh, hd),
-                 "v": vf.reshape(num_pages, ps, kvh, hd)}
+    with jax.named_scope(SC.KV_WRITE):
+        lp = jnp.minimum(pos // ps, pmax - 1)
+        phys = page_table[jnp.arange(b), lp]                   # [B]
+        flat_idx = jnp.where(phys >= 0, phys * ps + pos % ps,
+                             num_pages * ps)
+        kf = cache["k"].reshape(num_pages * ps, kvh, hd)
+        vf = cache["v"].reshape(num_pages * ps, kvh, hd)
+        kf = kf.at[flat_idx].set(k[:, 0].astype(kf.dtype), mode="drop")
+        vf = vf.at[flat_idx].set(v[:, 0].astype(vf.dtype), mode="drop")
+        new_cache = {"k": kf.reshape(num_pages, ps, kvh, hd),
+                     "v": vf.reshape(num_pages, ps, kvh, hd)}
 
     if use_flash and not window:
         from repro.kernels.flash_decode import flash_decode_paged
@@ -558,13 +562,14 @@ def attention_decode(cfg: ModelConfig, params, x, cache, pos, *,
         q = apply_rope(q, pos[:, None, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None, None], cfg.rope_theta)
 
-    slot = pos % s_cache if (window and window <= s_cache) else pos
-    slot = jnp.minimum(slot, s_cache - 1)
-    bidx = jnp.arange(b)
-    new_k = cache["k"].at[bidx, :, slot].set(
-        k[:, 0].astype(cache["k"].dtype))
-    new_v = cache["v"].at[bidx, :, slot].set(
-        v[:, 0].astype(cache["v"].dtype))
+    with jax.named_scope(SC.KV_WRITE):
+        slot = pos % s_cache if (window and window <= s_cache) else pos
+        slot = jnp.minimum(slot, s_cache - 1)
+        bidx = jnp.arange(b)
+        new_k = cache["k"].at[bidx, :, slot].set(
+            k[:, 0].astype(cache["k"].dtype))
+        new_v = cache["v"].at[bidx, :, slot].set(
+            v[:, 0].astype(cache["v"].dtype))
 
     q = q.reshape(b, dims.kv, dims.group, dims.head_dim)
     scale = 1.0 / np.sqrt(dims.head_dim)

@@ -25,6 +25,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import mamba as M
 from repro.models import moe as MOE
+from repro.models import scopes as SC
 from repro.core.types import Placement
 from repro.sharding.policy import Dist
 
@@ -417,16 +418,17 @@ def _mixer_apply(cfg, dist, lp, mixer, x, *, mode, lc, pos, chunk,
     s = x.shape[1]
     buf_k, buf_v = lc["k"], lc["v"]
     w = buf_k.shape[2]
-    if window and w <= s:
-        kw, vw = k[:, :, -w:], v[:, :, -w:]
-        slots = (jnp.arange(s - w, s) % w)
-        new_k = buf_k.at[:, :, slots].set(kw.astype(buf_k.dtype))
-        new_v = buf_v.at[:, :, slots].set(vw.astype(buf_v.dtype))
-    else:
-        new_k = jax.lax.dynamic_update_slice_in_dim(
-            buf_k, k.astype(buf_k.dtype), 0, axis=2)
-        new_v = jax.lax.dynamic_update_slice_in_dim(
-            buf_v, v.astype(buf_v.dtype), 0, axis=2)
+    with jax.named_scope(SC.KV_WRITE):
+        if window and w <= s:
+            kw, vw = k[:, :, -w:], v[:, :, -w:]
+            slots = (jnp.arange(s - w, s) % w)
+            new_k = buf_k.at[:, :, slots].set(kw.astype(buf_k.dtype))
+            new_v = buf_v.at[:, :, slots].set(vw.astype(buf_v.dtype))
+        else:
+            new_k = jax.lax.dynamic_update_slice_in_dim(
+                buf_k, k.astype(buf_k.dtype), 0, axis=2)
+            new_v = jax.lax.dynamic_update_slice_in_dim(
+                buf_v, v.astype(buf_v.dtype), 0, axis=2)
     return y, {"k": new_k, "v": new_v}
 
 
@@ -491,12 +493,13 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
     dp = dist.dp_axes
     params = cast_params(params, compute_dtype)
 
-    if cfg.input_mode == "embeddings" and mode != "decode":
-        x = embeds
-    else:
-        x = params["embed"][tokens]
-    x = x.astype(compute_dtype)
-    x = dist.shard(x, dp, None, None)
+    with jax.named_scope(SC.EMBED):
+        if cfg.input_mode == "embeddings" and mode != "decode":
+            x = embeds
+        else:
+            x = params["embed"][tokens]
+        x = x.astype(compute_dtype)
+        x = dist.shard(x, dp, None, None)
 
     routing = routing or {}
     cache = cache or {}
@@ -509,22 +512,28 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
         for i, (mixer, ffn) in enumerate(kinds):
             li = f"l{i}"
             lp = bp[li]
-            h = L.apply_norm(cfg, lp["norm1"], x)
-            y, nc = _mixer_apply(cfg, dist, lp, mixer, h, mode=mode,
-                                 lc=bc.get(li), pos=pos, chunk=chunk,
-                                 slot_idx=slot_idx, page_table=page_table,
-                                 row_valid=row_valid,
-                                 use_flash=use_flash_kernel)
+            with jax.named_scope(
+                    SC.MAMBA if mixer == "mamba" else SC.ATTENTION):
+                h = L.apply_norm(cfg, lp["norm1"], x)
+                y, nc = _mixer_apply(cfg, dist, lp, mixer, h, mode=mode,
+                                     lc=bc.get(li), pos=pos, chunk=chunk,
+                                     slot_idx=slot_idx,
+                                     page_table=page_table,
+                                     row_valid=row_valid,
+                                     use_flash=use_flash_kernel)
             if nc:
                 new_bc[li] = nc
             # cast keeps the residual stream in the compute dtype even
             # when the mixer read a wider KV pool (kv_dtype="fp32")
             x = x + y.astype(x.dtype)
-            if ffn != "none":
-                h2 = L.apply_norm(cfg, lp["norm2"], x)
-                if ffn == "dense":
+            if ffn == "dense":
+                with jax.named_scope(SC.MLP):
+                    h2 = L.apply_norm(cfg, lp["norm2"], x)
                     y2 = L.apply_mlp(cfg, lp["mlp"], h2, dist=dist)
-                else:
+                x = x + y2.astype(x.dtype)
+            elif ffn == "moe":
+                with jax.named_scope(SC.MOE):
+                    h2 = L.apply_norm(cfg, lp["norm2"], x)
                     if moe_mode == "features":
                         h2f = h2[:, 0]          # [B, 1, d] -> [B, d]
                         y2, st = MOE.moe_ffn(
@@ -541,7 +550,7 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
                             capacity_factor=capacity_factor,
                             use_pallas_route=use_pallas_route,
                             row_valid=row_valid)
-                    stats_l.append(st)
+                stats_l.append(st)
                 x = x + y2.astype(x.dtype)
         if stats_l:
             stats = jax.tree.map(lambda *v: jnp.stack(v), *stats_l)
@@ -554,16 +563,18 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
         body = jax.checkpoint(
             block_body, policy=_REMAT_POLICIES[remat_policy]())
 
-    x, (new_cache, stats) = jax.lax.scan(
-        body, x, (params["blocks"], cache, routing))
+    with jax.named_scope(SC.LAYERS):
+        x, (new_cache, stats) = jax.lax.scan(
+            body, x, (params["blocks"], cache, routing))
 
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    if cfg.tie_embeddings and cfg.input_mode == "tokens":
-        unembed = params["embed"].T
-    else:
-        unembed = params["unembed"]
-    logits = x @ unembed.astype(x.dtype)
-    logits = dist.shard(logits, dp, None, dist.tp_axis)
+    with jax.named_scope(SC.HEAD):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        if cfg.tie_embeddings and cfg.input_mode == "tokens":
+            unembed = params["embed"].T
+        else:
+            unembed = params["unembed"]
+        logits = x @ unembed.astype(x.dtype)
+        logits = dist.shard(logits, dp, None, dist.tp_axis)
 
     # reduce per-(block, layer) stats
     if stats:

@@ -43,6 +43,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import routing as core_routing
 from repro.core.types import Placement
+from repro.models import scopes as SC
 from repro.sharding.policy import Dist
 
 _INT = jnp.int32
@@ -282,50 +283,52 @@ def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
     t, d = x.shape
     k = ids.shape[-1]
     s_l, _, n_up, fe = w_up.shape
-    buf_pair, group_pad, tile_group, _n_live = build_pair_buffer(
-        slots, lo, s_loc, capacity, tile)
-    row_valid = buf_pair >= 0
-    tok = jnp.where(row_valid, buf_pair // k, 0)
-    xg = jnp.where(row_valid[:, None], x[tok], 0).astype(x.dtype)
+    with jax.named_scope(SC.DISPATCH):
+        buf_pair, group_pad, tile_group, _n_live = build_pair_buffer(
+            slots, lo, s_loc, capacity, tile)
+        row_valid = buf_pair >= 0
+        tok = jnp.where(row_valid, buf_pair // k, 0)
+        xg = jnp.where(row_valid[:, None], x[tok], 0).astype(x.dtype)
 
-    if impl == "fused":
-        from repro.kernels.moe_ffn import fused_expert_ffn_pallas
-        y = fused_expert_ffn_pallas(
-            xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
-            w_down.astype(x.dtype), tile_group, gated=cfg.gated_mlp)
-        y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
-    elif impl == "fused_paged":
-        # the double-buffered paged megakernel, driven here with the
-        # identity slot->frame map (all local slots resident in order);
-        # the expert-pool bench exercises permuted maps directly
-        from repro.kernels.moe_ffn import fused_expert_ffn_paged_pallas
-        y = fused_expert_ffn_paged_pallas(
-            xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
-            w_down.astype(x.dtype), jnp.arange(s_l, dtype=jnp.int32),
-            tile_group, gated=cfg.gated_mlp)
-        y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
-    else:
-        h = grouped_matmul(
-            xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
-            group_pad, tile_group, impl)
-        if cfg.gated_mlp:
-            g, u = jnp.split(h, 2, axis=-1)
-            h = jax.nn.silu(g) * u
+    with jax.named_scope(SC.EXPERTS):
+        w_up2 = w_up.reshape(s_l, d, n_up * fe).astype(x.dtype)
+        w_down2 = w_down.astype(x.dtype)
+        if impl == "fused":
+            from repro.kernels.moe_ffn import fused_expert_ffn_pallas
+            y = fused_expert_ffn_pallas(xg, w_up2, w_down2, tile_group,
+                                        gated=cfg.gated_mlp)
+            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
+        elif impl == "fused_paged":
+            # the double-buffered paged megakernel, driven here with the
+            # identity slot->frame map (all local slots resident in
+            # order); the expert-pool bench exercises permuted maps
+            from repro.kernels.moe_ffn import fused_expert_ffn_paged_pallas
+            y = fused_expert_ffn_paged_pallas(
+                xg, w_up2, w_down2, jnp.arange(s_l, dtype=jnp.int32),
+                tile_group, gated=cfg.gated_mlp)
+            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
         else:
-            h = jax.nn.gelu(h)
-        # named for the save_moe remat policy: saving just these two
-        # grouped matmuls avoids recomputing the dominant expert FLOPs
-        # in backward while attention still remats (perf iteration,
-        # EXPERIMENTS.md §Perf)
-        h = jax.ad_checkpoint.checkpoint_name(h, "moe_h")
-        y = grouped_matmul(h.astype(x.dtype), w_down.astype(x.dtype),
-                           group_pad, tile_group, impl)
-        y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
+            h = grouped_matmul(xg, w_up2, group_pad, tile_group, impl)
+            if cfg.gated_mlp:
+                g, u = jnp.split(h, 2, axis=-1)
+                h = jax.nn.silu(g) * u
+            else:
+                h = jax.nn.gelu(h)
+            # named for the save_moe remat policy: saving just these two
+            # grouped matmuls avoids recomputing the dominant expert
+            # FLOPs in backward while attention still remats (perf
+            # iteration, EXPERIMENTS.md §Perf)
+            h = jax.ad_checkpoint.checkpoint_name(h, "moe_h")
+            y = grouped_matmul(h.astype(x.dtype), w_down2, group_pad,
+                               tile_group, impl)
+            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
 
-    gate = jnp.where(row_valid, gates.reshape(-1)[jnp.maximum(buf_pair, 0)], 0.0)
-    y = y.astype(jnp.float32) * gate[:, None]
-    out = jnp.zeros((t, d), jnp.float32).at[tok].add(
-        jnp.where(row_valid[:, None], y, 0.0))
+    with jax.named_scope(SC.COMBINE):
+        gate = jnp.where(row_valid,
+                         gates.reshape(-1)[jnp.maximum(buf_pair, 0)], 0.0)
+        y = y.astype(jnp.float32) * gate[:, None]
+        out = jnp.zeros((t, d), jnp.float32).at[tok].add(
+            jnp.where(row_valid[:, None], y, 0.0))
     return out
 
 
@@ -359,19 +362,22 @@ def _moe_inner(cfg: ModelConfig, params, tables, x, *, algo, lo, s_loc,
     EPLB round-robin ranks, METRO's activation decisions, or the
     expert-load stats that drive rebalancing — and routing becomes
     bitwise-invariant to how much a serving batch was padded."""
-    ids, gates, probs = gating(cfg, params["w_router"], x)
-    if row_valid is not None:
-        ids = jnp.where(row_valid[:, None], ids, -1)
-    hist = core_routing.topk_histogram(ids, cfg.num_experts)
-    slots = core_routing.route(
-        algo, ids, hist, tables["expert_slots"], tables["num_replicas"],
-        num_devices=ep_size, slots_per_device=slots_per_device,
-        use_pallas=use_pallas_route)
+    with jax.named_scope(SC.ROUTER):
+        ids, gates, probs = gating(cfg, params["w_router"], x)
+        if row_valid is not None:
+            ids = jnp.where(row_valid[:, None], ids, -1)
+        hist = core_routing.topk_histogram(ids, cfg.num_experts)
+    with jax.named_scope(SC.ROUTE):
+        slots = core_routing.route(
+            algo, ids, hist, tables["expert_slots"],
+            tables["num_replicas"], num_devices=ep_size,
+            slots_per_device=slots_per_device, use_pallas=use_pallas_route)
     out = _expert_compute(
         cfg, params["w_up"], params["w_down"], x, ids, gates, slots,
         lo=lo, s_loc=s_loc, capacity=capacity, tile=tile, impl=impl)
     if cfg.num_shared_experts:
-        out = out + _shared_expert(cfg, params, x)
+        with jax.named_scope(SC.SHARED_EXPERT):
+            out = out + _shared_expert(cfg, params, x)
     from repro.core import metrics as m
     act = m.activated_per_device(slots, ep_size, slots_per_device)
     stats = {
@@ -497,26 +503,30 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
 
         def body(xb, rvb, w_up, w_down, w_router, shared, es, nr):
             rank = jax.lax.axis_index(ax)
-            # FSDP-gather the fe shards within the data row (cast to the
-            # compute dtype first: halves the gather traffic)
-            w_up = w_up.astype(xb.dtype)
-            w_down = w_down.astype(xb.dtype)
-            if etp_w:
-                w_up = jax.lax.all_gather(w_up, etp_w, axis=3, tiled=True)
-                w_down = jax.lax.all_gather(w_down, etp_w, axis=1,
-                                            tiled=True)
-            xg = (jax.lax.all_gather(xb, ax, axis=1, tiled=True)
-                  if gather else xb)
-            rvg = (jax.lax.all_gather(rvb, ax, axis=1, tiled=True)
-                   if gather else rvb)
+            with jax.named_scope(SC.COLLECTIVE):
+                # FSDP-gather the fe shards within the data row (cast to
+                # the compute dtype first: halves the gather traffic)
+                w_up = w_up.astype(xb.dtype)
+                w_down = w_down.astype(xb.dtype)
+                if etp_w:
+                    w_up = jax.lax.all_gather(w_up, etp_w, axis=3,
+                                              tiled=True)
+                    w_down = jax.lax.all_gather(w_down, etp_w, axis=1,
+                                                tiled=True)
+                xg = (jax.lax.all_gather(xb, ax, axis=1, tiled=True)
+                      if gather else xb)
+                rvg = (jax.lax.all_gather(rvb, ax, axis=1, tiled=True)
+                       if gather else rvb)
+                su = sd = None
+                if shared is not None:
+                    su, sd = shared
+                    if sh_ok:  # gather the data-axis part; keep EP shard
+                        su = jax.lax.all_gather(su, etp, axis=2, tiled=True)
+                        sd = jax.lax.all_gather(sd, etp, axis=0, tiled=True)
             bl = xg.shape[0]
             x2 = xg.reshape(-1, d)
             p = {"w_router": w_router, "w_up": w_up, "w_down": w_down}
             if shared is not None:
-                su, sd = shared
-                if sh_ok:  # gather the data-axis part; keep EP shard
-                    su = jax.lax.all_gather(su, etp, axis=2, tiled=True)
-                    sd = jax.lax.all_gather(sd, etp, axis=0, tiled=True)
                 p["shared_up"], p["shared_down"] = su, sd
             out, stats = _moe_inner(
                 cfg, p, {"expert_slots": es, "num_replicas": nr}, x2,
@@ -525,12 +535,13 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
                 use_pallas_route=use_pallas_route,
                 row_valid=rvg.reshape(-1))
             out = out.astype(xb.dtype).reshape(bl, -1, d)
-            if gather:
-                out = jax.lax.psum_scatter(out, ax, scatter_dimension=1,
-                                           tiled=True)
-            else:
-                out = jax.lax.psum(out, ax)
-            return out, _reduce_stats(stats, all_axes)
+            with jax.named_scope(SC.COLLECTIVE):
+                if gather:
+                    out = jax.lax.psum_scatter(out, ax, scatter_dimension=1,
+                                               tiled=True)
+                else:
+                    out = jax.lax.psum(out, ax)
+                return out, _reduce_stats(stats, all_axes)
 
         out, stats = jax.shard_map(
             body, mesh=mesh,
@@ -565,8 +576,9 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
 
     def body_f(xb, rvb, w_up, w_down, w_router, shared, es, nr):
         rank = jax.lax.axis_index(ax)
-        xg = (jax.lax.all_gather(xb, gather_axes, axis=1, tiled=True)
-              if gather else xb)
+        with jax.named_scope(SC.COLLECTIVE):
+            xg = (jax.lax.all_gather(xb, gather_axes, axis=1, tiled=True)
+                  if gather else xb)
         p = {"w_router": w_router, "w_up": w_up, "w_down": w_down}
         if shared is not None:
             p["shared_up"], p["shared_down"] = shared
@@ -577,12 +589,13 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
             use_pallas_route=use_pallas_route, row_valid=rvb)
         # combine over slots (EP axis) AND fe shards (ETP axis) in one
         # collective; weights never moved.
-        if gather:
-            out = jax.lax.psum_scatter(out, gather_axes,
-                                       scatter_dimension=1, tiled=True)
-        else:
-            out = jax.lax.psum(out, gather_axes)
-        return out.astype(xb.dtype), _reduce_stats(stats, all_axes)
+        with jax.named_scope(SC.COLLECTIVE):
+            if gather:
+                out = jax.lax.psum_scatter(out, gather_axes,
+                                           scatter_dimension=1, tiled=True)
+            else:
+                out = jax.lax.psum(out, gather_axes)
+            return out.astype(xb.dtype), _reduce_stats(stats, all_axes)
 
     out, stats = jax.shard_map(
         body_f, mesh=mesh,

@@ -27,6 +27,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import build_placement
 from repro.models import lm as LM
+from repro.models import scopes as SC
 from repro.serving.expert_pool import build_expert_pool
 from repro.serving.kv import pages_for
 from repro.serving.scheduler import _pow2
@@ -174,6 +175,7 @@ class Executor:
             paged = ecfg.kv_layout == "paged"
 
             @jax.jit
+            @jax.named_scope(SC.DECODE_STEP)
             def step(params, tokens, pos, slot_idx, page_table, cache,
                      routing):
                 logits, new_cache, stats = LM.apply_lm(
@@ -185,7 +187,9 @@ class Executor:
                     page_table=page_table if paged else None,
                     row_valid=slot_idx < ecfg.max_batch,
                     use_flash_kernel=ecfg.use_flash_kernel)
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                with jax.named_scope(SC.HEAD):
+                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(
+                        jnp.int32)
                 return nxt, new_cache, stats
             return step
         return self._get_fn("decode", bucket, build)
@@ -196,6 +200,7 @@ class Executor:
             paged = ecfg.kv_layout == "paged"
 
             @jax.jit
+            @jax.named_scope(SC.WAVE_STEP)
             def step(params, tokens, lengths, slot_idx, page_table, cache,
                      routing):
                 wave = LM.init_wave_cache(cfg, dist, batch, length)
@@ -224,6 +229,7 @@ class Executor:
             c = ecfg.prefill_chunk
 
             @jax.jit
+            @jax.named_scope(SC.CHUNK_STEP)
             def step(params, tokens, start, n_tok, slot_idx, page_table,
                      cache, routing):
                 _, new_cache, stats = LM.apply_lm(
@@ -248,6 +254,7 @@ class Executor:
             c = ecfg.prefill_chunk
 
             @jax.jit
+            @jax.named_scope(SC.MIXED_STEP)
             def step(params, p_tokens, p_start, p_ntok, p_slot, p_pt,
                      d_tokens, d_pos, d_slot, d_pt, cache, routing):
                 _, cache1, st_p = LM.apply_lm(
@@ -265,7 +272,9 @@ class Executor:
                     slot_idx=d_slot, page_table=d_pt,
                     row_valid=d_slot < ecfg.max_batch,
                     use_flash_kernel=ecfg.use_flash_kernel)
-                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                with jax.named_scope(SC.HEAD):
+                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(
+                        jnp.int32)
                 return nxt, cache2, st_p, st_d
             return step
         return self._get_fn("mixed", (bp, bd), build)
