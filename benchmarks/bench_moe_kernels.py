@@ -149,7 +149,7 @@ def run(fast: bool = False, seed: int = 0):
             lambda *a: fused_expert_ffn_pallas(
                 *a, gated=case.gated, tile_k_up=tile_k_up,
                 tile_k_dn=tile_k_dn, interpret=True),
-            x, wu, wd, tg)
+            x, wu.reshape(case.s_loc, case.d, n_up, case.fe), wd, tg)
 
         rows.append((
             f"moe_kernel_{case.name}", us_fused,

@@ -27,6 +27,9 @@ Two kernels:
     resident token tile.  Grid ``(m_tiles, k_up_tiles + k_down_tiles)``
     — the second dimension enumerates the up phases then the down
     phases; scratch persists across phases of the same token tile.
+    It reads the layer-stacked weights as stored ([L, S, d, n_up, fe]
+    and [L, S, fe, d]) at a scalar-prefetched layer index, so no layer
+    is sliced out of the stack or relaid before it.
     Semantics == ref.fused_expert_ffn_ref.
 
 VMEM sizing rule (see kernels/README.md): the fused kernel keeps
@@ -182,9 +185,37 @@ def _activate(h, fe: int, gated: bool):
     return act.astype(h.dtype)
 
 
-def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
+def _split_up(wu_ref, n_up: int, fe: int):
+    """The [tile_k, fe] gate and up weight tiles of a (1, 1, tile_k,
+    n_up, fe) up block, as stored.
+
+    On a TPU the bf16 (n_up=2, fe) minor dimensions are tiled
+    ``T(2,128)(2,1)``: one 32-bit word holds a gate weight in its low
+    half and the up weight beside it in its high half, and a k-row's fe
+    words are contiguous.  So the block is read as [tile_k * fe/128,
+    128] words, k-rows 2i and 2i + 1 of each 128-lane block by sublane-
+    strided loads, and their gate (up) halves are packed into one word:
+    rows 2i, 2i + 1 of the bf16 gate (up) tile, the layout the MXU
+    takes.  Other dtypes and widths (the interpreter's test sizes) index
+    the block."""
+    tk = wu_ref.shape[2]
+    if n_up == 1 or wu_ref.dtype != jnp.bfloat16 or fe % 128 or tk % 2:
+        return [wu_ref[0, 0, :, u, :] for u in range(n_up)]
+    lanes = fe // 128
+    words = wu_ref.bitcast(jnp.uint32).reshape(tk * lanes, 128)
+    lo, hi = jnp.uint32(0xFFFF), jnp.uint32(0xFFFF0000)
+    gate, up = [], []
+    for j in range(lanes):
+        even = words[pl.ds(j, tk // 2, stride=2 * lanes), :]
+        odd = words[pl.ds(lanes + j, tk // 2, stride=2 * lanes), :]
+        gate.append(pltpu.bitcast((even & lo) | (odd << 16), jnp.bfloat16))
+        up.append(pltpu.bitcast((even >> 16) | (odd & hi), jnp.bfloat16))
+    return [jnp.concatenate(gate, axis=1), jnp.concatenate(up, axis=1)]
+
+
+def _fused_kernel(tile_group, n_live, layer, x_ref, wu_ref, wd_ref, out_ref,
                   h_ref, hg_ref, acc_ref, *, k_up: int, k_dn: int,
-                  tile_k_dn: int, fe: int, gated: bool):
+                  tile_k_dn: int, fe: int, n_up: int, gated: bool):
     i = pl.program_id(0)
     j = pl.program_id(1)
     live = tile_group[i] >= 0
@@ -195,10 +226,14 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # ---- up phases: accumulate the hidden in fp32 VMEM --------------
+    # the up block is [tile_k_up, n_up, fe] as stored: gate and up are
+    # taken apart here, one dot each into their half of the hidden
     @pl.when(live & (j < k_up))
     def _up():
-        h_ref[...] += jnp.dot(x_ref[...], wu_ref[0],
-                              preferred_element_type=jnp.float32)
+        xb = x_ref[...]
+        for u, w in enumerate(_split_up(wu_ref, n_up, fe)):
+            h_ref[:, u * fe:(u + 1) * fe] += jnp.dot(
+                xb, w, preferred_element_type=jnp.float32)
 
     # ---- gate in VMEM after the last up k-tile ----------------------
     @pl.when(live & (j == k_up - 1))
@@ -216,7 +251,7 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
         kf = j - k_up
         off = pl.multiple_of(kf * tile_k_dn, tile_k_dn)
         hblk = hg_ref[:, pl.ds(off, tile_k_dn)]
-        acc_ref[...] += jnp.dot(hblk, wd_ref[0],
+        acc_ref[...] += jnp.dot(hblk, wd_ref[0, 0],
                                 preferred_element_type=jnp.float32)
 
     @pl.when(live & (j == k_up + k_dn - 1))
@@ -232,25 +267,32 @@ def _fused_kernel(tile_group, n_live, x_ref, wu_ref, wd_ref, out_ref,
     jax.jit,
     static_argnames=("gated", "tile_m", "tile_k_up", "tile_k_dn",
                      "interpret"))
-def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
-                            tile_m: int = 0, tile_k_up: int = 512,
-                            tile_k_dn: int = 512, interpret=None):
-    """One-pass expert FFN: out = act(x @ w_up[g]) @ w_down[g] per tile.
+def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, layer=0, *,
+                            gated: bool, tile_m: int = 0,
+                            tile_k_up: int = 512, tile_k_dn: int = 512,
+                            interpret=None):
+    """One-pass expert FFN: out = act(x @ w_up[layer, g]) @
+    w_down[layer, g] per tile.
 
     x: [C, d] sorted/tile-aligned buffer (C = n_tiles * tile_m);
-    w_up: [S, d, n_up*fe] (n_up = 2 when ``gated``: [gate | up] halves);
-    w_down: [S, fe, d]; tile_group: [n_tiles] int32, -1 = dead tile.
+    w_up: [L, S, d, n_up, fe] and w_down: [L, S, fe, d], the layer-
+    stacked expert weights as stored (n_up = 2 when ``gated``: gate,
+    up); a single layer's [S, d, n_up, fe] / [S, fe, d] is read as
+    L = 1.  ``layer``: int32 scalar, the layer read (scalar-prefetched:
+    the stacks are read in place, never sliced or relaid).
+    tile_group: [n_tiles] int32, -1 = dead tile.
     Returns [C, d] in x.dtype; dead tiles yield exact zeros.
 
     The hidden activation never leaves VMEM and each live tile streams
     its group's up+down weights exactly once (dead tiles: no DMA, no
     FLOPs — their block indices repeat the last live tile's).
     """
+    if w_up.ndim == 4:
+        w_up, w_down = w_up[None], w_down[None]
     c, d = x.shape
-    s, _, f_up = w_up.shape
-    _, fe, _ = w_down.shape
-    n_up = 2 if gated else 1
-    assert f_up == n_up * fe, (f_up, n_up, fe)
+    _, s, _, n_up, fe = w_up.shape
+    assert n_up == (2 if gated else 1), (n_up, gated)
+    assert w_down.shape[2:] == (fe, d), (w_down.shape, fe, d)
     n_tiles = tile_group.shape[0]
     tile_m = tile_m or c // n_tiles
     assert c == n_tiles * tile_m, (c, n_tiles, tile_m)
@@ -261,11 +303,12 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
 
     tile_group = tile_group.astype(jnp.int32)
     n_live = jnp.sum(tile_group >= 0).astype(jnp.int32)[None]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     grid = (n_tiles, k_up + k_dn)
     kernel = functools.partial(
         _fused_kernel, k_up=k_up, k_dn=k_dn, tile_k_dn=tile_k_dn, fe=fe,
-        gated=gated)
+        n_up=n_up, gated=gated)
 
     def _g(i, nl, tg):
         return jnp.maximum(tg[_dma_row(i, nl)], 0)
@@ -273,7 +316,7 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 # x k-tile: advances over the up phases, parks on the
@@ -281,32 +324,33 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
                 # dead tiles park on the last live tile's final index
                 pl.BlockSpec(
                     (tile_m, tile_k_up),
-                    lambda i, j, tg, nl: (
+                    lambda i, j, tg, nl, ly: (
                         _dma_row(i, nl),
                         _freeze(i, nl, jnp.minimum(j, k_up - 1),
                                 k_up - 1))),
-                # up-weight tile: advances over up phases, parks after
+                # up-weight tile of the layer's stack: advances over up
+                # phases, parks after
                 pl.BlockSpec(
-                    (1, tile_k_up, f_up),
-                    lambda i, j, tg, nl: (
-                        _g(i, nl, tg),
+                    (1, 1, tile_k_up, n_up, fe),
+                    lambda i, j, tg, nl, ly: (
+                        ly[0], _g(i, nl, tg),
                         _freeze(i, nl, jnp.minimum(j, k_up - 1),
-                                k_up - 1), 0)),
+                                k_up - 1), 0, 0)),
                 # down-weight tile: parks on 0 during up phases (its
                 # single prefetch is the tile the first down phase
                 # needs), advances over the down phases; dead tiles
                 # park on the final down index
                 pl.BlockSpec(
-                    (1, tile_k_dn, d),
-                    lambda i, j, tg, nl: (
-                        _g(i, nl, tg),
+                    (1, 1, tile_k_dn, d),
+                    lambda i, j, tg, nl, ly: (
+                        ly[0], _g(i, nl, tg),
                         _freeze(i, nl, jnp.maximum(j - k_up, 0),
                                 k_dn - 1), 0)),
             ],
             out_specs=pl.BlockSpec((tile_m, d),
-                                   lambda i, j, tg, nl: (i, 0)),
+                                   lambda i, j, tg, nl, ly: (i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((tile_m, f_up), jnp.float32),   # hidden acc
+                pltpu.VMEM((tile_m, n_up * fe), jnp.float32),  # hidden
                 pltpu.VMEM((tile_m, fe), x.dtype),         # gated hidden
                 pltpu.VMEM((tile_m, d), jnp.float32),      # output acc
             ],
@@ -315,7 +359,7 @@ def fused_expert_ffn_pallas(x, w_up, w_down, tile_group, *, gated: bool,
         interpret=interpret_mode(interpret),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-    )(tile_group, n_live, x, w_up, w_down)
+    )(tile_group, n_live, layer, x, w_up, w_down)
 
 
 # ----------------------------------------------------------------------
@@ -403,8 +447,9 @@ def fused_expert_ffn_paged_pallas(x, wu_pool, wd_pool, frame_map,
     (no revisit-skip in the manual path) — acceptable at the pool's
     page granularity; see kernels/README.md.
 
-    Semantics == fused_expert_ffn_pallas(x, wu_pool[frame_map],
-    wd_pool[frame_map], tile_group) == ref.fused_expert_ffn_ref.
+    Semantics == fused_expert_ffn_pallas(x, wu_pool[frame_map] as
+    [S, d, n_up, fe], wd_pool[frame_map], tile_group) ==
+    ref.fused_expert_ffn_ref.
     """
     c, d = x.shape
     _, _, f_up = wu_pool.shape

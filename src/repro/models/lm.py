@@ -505,8 +505,24 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
     cache = cache or {}
     moe_mode = "features" if mode == "decode" else "tokens"
 
+    # the fused expert kernel reads the MoE layers' expert weights from
+    # their layer stacks in place (the scan's layer index is its
+    # scalar-prefetched block index), so the scan does not slice them;
+    # it still slices them for every other datapath, and for training,
+    # where the kernel has no VJP
+    blocks, expert_stacks = params["blocks"], {}
+    if moe_impl == "fused" and mode != "train":
+        blocks = dict(blocks)
+        for i, (_, ffn) in enumerate(kinds):
+            if ffn == "moe":
+                li = f"l{i}"
+                moe_p = dict(blocks[li]["moe"])
+                expert_stacks[li] = {k: moe_p.pop(k)
+                                     for k in ("w_up", "w_down")}
+                blocks[li] = dict(blocks[li], moe=moe_p)
+
     def block_body(x, blk):
-        bp, bc, brt = blk
+        bp, bc, brt, layer = blk
         new_bc = {}
         stats_l = []
         for i, (mixer, ffn) in enumerate(kinds):
@@ -532,24 +548,27 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
                     y2 = L.apply_mlp(cfg, lp["mlp"], h2, dist=dist)
                 x = x + y2.astype(x.dtype)
             elif ffn == "moe":
+                moe_p, moe_layer = lp["moe"], None
+                if li in expert_stacks:
+                    moe_p, moe_layer = dict(moe_p, **expert_stacks[li]), layer
                 with jax.named_scope(SC.MOE):
                     h2 = L.apply_norm(cfg, lp["norm2"], x)
                     if moe_mode == "features":
                         h2f = h2[:, 0]          # [B, 1, d] -> [B, d]
                         y2, st = MOE.moe_ffn(
-                            cfg, dist, lp["moe"], brt[li], h2f, algo=algo,
+                            cfg, dist, moe_p, brt[li], h2f, algo=algo,
                             impl=moe_impl, mode="features",
                             capacity_factor=capacity_factor,
                             use_pallas_route=use_pallas_route,
-                            row_valid=row_valid)
+                            row_valid=row_valid, layer=moe_layer)
                         y2 = y2[:, None]
                     else:
                         y2, st = MOE.moe_ffn(
-                            cfg, dist, lp["moe"], brt[li], h2, algo=algo,
+                            cfg, dist, moe_p, brt[li], h2, algo=algo,
                             impl=moe_impl, mode="tokens",
                             capacity_factor=capacity_factor,
                             use_pallas_route=use_pallas_route,
-                            row_valid=row_valid)
+                            row_valid=row_valid, layer=moe_layer)
                 stats_l.append(st)
                 x = x + y2.astype(x.dtype)
         if stats_l:
@@ -565,7 +584,7 @@ def apply_lm(cfg: ModelConfig, dist: Dist, params, *, tokens=None,
 
     with jax.named_scope(SC.LAYERS):
         x, (new_cache, stats) = jax.lax.scan(
-            body, x, (params["blocks"], cache, routing))
+            body, x, (blocks, cache, routing, jnp.arange(n_blocks)))
 
     with jax.named_scope(SC.HEAD):
         x = L.apply_norm(cfg, params["final_norm"], x)

@@ -267,22 +267,23 @@ def grouped_matmul(x, w, group_pad, tile_group, impl: str):
 
 def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
                     *, lo, s_loc: int, capacity: int, tile: int,
-                    impl: str):
+                    impl: str, layer=None):
     """Grouped FFN over local slots; returns partial output [T, d] f32.
 
     w_up: [S_loc, d, n_up, fe_shard]; w_down: [S_loc, fe_shard, d] —
     fe_shard may be a proper shard (ETP); the caller psums over the ETP
-    axis.
+    axis.  With ``layer`` (``impl="fused"`` only) they are the layer-
+    stacked [L, ...] weights and ``layer`` the one read.
 
     ``impl="fused"`` collapses the two grouped matmuls + gating into
     ONE Pallas megakernel call (kernels/moe_ffn.fused_expert_ffn_pallas):
     the ``[C, n_up*fe]`` hidden never materializes in HBM and each
     activated expert's weights stream exactly once per resident token
-    tile (no ``moe_h`` remat point exists on this path — there is no
-    hidden to save)."""
+    tile, read in place from the stored layout (no ``moe_h`` remat
+    point exists on this path — there is no hidden to save)."""
     t, d = x.shape
     k = ids.shape[-1]
-    s_l, _, n_up, fe = w_up.shape
+    s_l, _, n_up, fe = w_up.shape[-4:]
     with jax.named_scope(SC.DISPATCH):
         buf_pair, group_pad, tile_group, _n_live = build_pair_buffer(
             slots, lo, s_loc, capacity, tile)
@@ -291,24 +292,25 @@ def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
         xg = jnp.where(row_valid[:, None], x[tok], 0).astype(x.dtype)
 
     with jax.named_scope(SC.EXPERTS):
-        w_up2 = w_up.reshape(s_l, d, n_up * fe).astype(x.dtype)
-        w_down2 = w_down.astype(x.dtype)
         if impl == "fused":
             from repro.kernels.moe_ffn import fused_expert_ffn_pallas
-            y = fused_expert_ffn_pallas(xg, w_up2, w_down2, tile_group,
-                                        gated=cfg.gated_mlp)
-            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
+            y = fused_expert_ffn_pallas(
+                xg, w_up.astype(x.dtype), w_down.astype(x.dtype),
+                tile_group, 0 if layer is None else layer,
+                gated=cfg.gated_mlp)
         elif impl == "fused_paged":
             # the double-buffered paged megakernel, driven here with the
             # identity slot->frame map (all local slots resident in
             # order); the expert-pool bench exercises permuted maps
             from repro.kernels.moe_ffn import fused_expert_ffn_paged_pallas
             y = fused_expert_ffn_paged_pallas(
-                xg, w_up2, w_down2, jnp.arange(s_l, dtype=jnp.int32),
+                xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
+                w_down.astype(x.dtype), jnp.arange(s_l, dtype=jnp.int32),
                 tile_group, gated=cfg.gated_mlp)
-            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
         else:
-            h = grouped_matmul(xg, w_up2, group_pad, tile_group, impl)
+            h = grouped_matmul(
+                xg, w_up.reshape(s_l, d, n_up * fe).astype(x.dtype),
+                group_pad, tile_group, impl)
             if cfg.gated_mlp:
                 g, u = jnp.split(h, 2, axis=-1)
                 h = jax.nn.silu(g) * u
@@ -319,9 +321,9 @@ def _expert_compute(cfg: ModelConfig, w_up, w_down, x, ids, gates, slots,
             # FLOPs in backward while attention still remats (perf
             # iteration, EXPERIMENTS.md §Perf)
             h = jax.ad_checkpoint.checkpoint_name(h, "moe_h")
-            y = grouped_matmul(h.astype(x.dtype), w_down2, group_pad,
-                               tile_group, impl)
-            y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
+            y = grouped_matmul(h.astype(x.dtype), w_down.astype(x.dtype),
+                               group_pad, tile_group, impl)
+        y = jax.ad_checkpoint.checkpoint_name(y, "moe_y")
 
     with jax.named_scope(SC.COMBINE):
         gate = jnp.where(row_valid,
@@ -353,7 +355,8 @@ def _shared_expert(cfg: ModelConfig, params, x):
 
 def _moe_inner(cfg: ModelConfig, params, tables, x, *, algo, lo, s_loc,
                capacity, tile, impl, ep_size, slots_per_device,
-               use_pallas_route=False, with_stats=True, row_valid=None):
+               use_pallas_route=False, with_stats=True, row_valid=None,
+               layer=None):
     """Router + routing + local grouped FFN. x: [T, d] (full EP-group
     tokens). Returns (partial_out [T, d] f32, stats).
 
@@ -374,7 +377,8 @@ def _moe_inner(cfg: ModelConfig, params, tables, x, *, algo, lo, s_loc,
             slots_per_device=slots_per_device, use_pallas=use_pallas_route)
     out = _expert_compute(
         cfg, params["w_up"], params["w_down"], x, ids, gates, slots,
-        lo=lo, s_loc=s_loc, capacity=capacity, tile=tile, impl=impl)
+        lo=lo, s_loc=s_loc, capacity=capacity, tile=tile, impl=impl,
+        layer=layer)
     if cfg.num_shared_experts:
         with jax.named_scope(SC.SHARED_EXPERT):
             out = out + _shared_expert(cfg, params, x)
@@ -420,7 +424,7 @@ def _capacity(t_group: int, k: int, *, algo: str, mode: str, ep: int,
 def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
             algo: str = "eplb", capacity_factor: float = 1.25,
             impl: str = "ragged", tile: int = 8, mode: str = "tokens",
-            use_pallas_route: bool = False, row_valid=None):
+            use_pallas_route: bool = False, row_valid=None, layer=None):
     """MoE FFN over x: [B, S, d] (tokens mode) or [T, d] (features mode).
 
     tokens mode: x sequence-sharded over EP axis -> paper's all-gather
@@ -430,13 +434,23 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
 
     ``row_valid`` (bool, x's token shape — [B, S] or [T]) excludes
     padding tokens from routing (see :func:`_moe_inner`).
+
+    ``layer`` (int32 scalar): ``params``' ``w_up`` / ``w_down`` are then
+    the layer-stacked expert weights ([L, R, ...]) and ``layer`` the
+    one this call reads.  The fused kernel reads it from the stack in
+    place; every other datapath, and the EP mesh, take their slice.
     """
     squeeze = x.ndim == 3
     d = x.shape[-1]
     ep, spd = dist.ep_size, dist.slots_per_device
     k = cfg.num_experts_per_tok
+    local = dist.mesh is None or dist.tp_axis is None
+    if layer is not None and not (local and impl == "fused"):
+        params = dict(params, w_up=params["w_up"][layer],
+                      w_down=params["w_down"][layer])
+        layer = None
 
-    if dist.mesh is None or dist.tp_axis is None:
+    if local:
         # virtual EP: all slots local, same math, no collectives
         x2 = x.reshape(-1, d) if squeeze else x
         rv = row_valid.reshape(-1) if row_valid is not None else None
@@ -447,7 +461,7 @@ def moe_ffn(cfg: ModelConfig, dist: Dist, params, tables, x, *,
             cfg, params, tables, x2, algo=algo, lo=0, s_loc=ep * spd,
             capacity=capacity, tile=tile, impl=impl, ep_size=ep,
             slots_per_device=spd, use_pallas_route=use_pallas_route,
-            row_valid=rv)
+            row_valid=rv, layer=layer)
         out = out.astype(x.dtype)
         return (out.reshape(x.shape) if squeeze else out), stats
 

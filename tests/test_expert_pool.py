@@ -395,7 +395,9 @@ class TestPagedKernel:
                 tg = jnp.asarray(tg_l, jnp.int32)
                 x = jnp.asarray(rng.normal(
                     size=(len(tg_l) * self.TILE, self.D)), jnp.float32)
-                a = fused_expert_ffn_pallas(x, wu, wd, tg, gated=gated)
+                a = fused_expert_ffn_pallas(
+                    x, wu.reshape(self.S, self.D, -1, self.FE), wd, tg,
+                    gated=gated)
                 b = fused_expert_ffn_paged_pallas(
                     x, wu, wd, jnp.arange(self.S, dtype=jnp.int32), tg,
                     gated=gated)

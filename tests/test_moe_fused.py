@@ -15,7 +15,8 @@ import pytest
 
 from repro.kernels import interpret_mode
 from repro.kernels import ref
-from repro.kernels.moe_ffn import fused_expert_ffn_pallas, grouped_ffn_pallas
+from repro.kernels.moe_ffn import (_activate, fused_expert_ffn_pallas,
+                                   grouped_ffn_pallas)
 from repro.models.moe import build_pair_buffer, grouped_matmul
 from repro.sim.roofline import expert_ffn_traffic, fused_weight_dma_tiles
 
@@ -45,6 +46,12 @@ def _build(rng, t, k, s_loc, tile, *, short_capacity=False,
     )(jnp.asarray(slots), 0, s_loc=s_loc, capacity=capacity, tile=tile)
     return (np.asarray(bp), np.asarray(gp), np.asarray(tg), int(nl),
             capacity)
+
+
+def _stored(wu, n_up):
+    """[S, d, n_up*fe] -> the stored [S, d, n_up, fe] the kernel reads."""
+    s, d, f = wu.shape
+    return wu.reshape(s, d, n_up, f // n_up)
 
 
 def _two_pass_ref(x, wu, wd, tile_group, *, gated):
@@ -85,7 +92,7 @@ class TestFusedKernelParity:
             wd = jnp.asarray(
                 rng.normal(size=(s_loc, fe, d)) * 0.2, dtype)
             got = np.asarray(fused_expert_ffn_pallas(
-                x, wu, wd, jnp.asarray(tg), gated=gated,
+                x, _stored(wu, n_up), wd, jnp.asarray(tg), gated=gated,
                 tile_k_up=8, tile_k_dn=8), np.float32)
             xf, uf, df = (np.asarray(a, np.float32) for a in (x, wu, wd))
             want = ref.fused_expert_ffn_ref(xf, uf, df, tg, gated=gated)
@@ -134,7 +141,8 @@ class TestFusedKernelParity:
             wd = jnp.asarray(
                 rng.normal(size=(s_loc, fe, d)) * 0.2, dtype)
             got = np.asarray(fused_expert_ffn_pallas(
-                x, wu, wd, jnp.asarray(tg), gated=gated), np.float32)
+                x, _stored(wu, n_up), wd, jnp.asarray(tg), gated=gated),
+                np.float32)
             xf, uf, df = (np.asarray(a, np.float32)
                           for a in (x, wu, wd))
             want = ref.fused_expert_ffn_ref(xf, uf, df, tg, gated=gated)
@@ -153,7 +161,7 @@ class TestFusedKernelParity:
         assert nl == 0 and (tg == -1).all()
         d, fe = 8, 12
         x = jnp.asarray(rng.normal(size=(capacity, d)), jnp.float32)
-        wu = jnp.asarray(np.full((3, d, 2 * fe), np.nan), jnp.float32)
+        wu = jnp.asarray(np.full((3, d, 2, fe), np.nan), jnp.float32)
         wd = jnp.asarray(np.full((3, fe, d), np.nan), jnp.float32)
         got = np.asarray(fused_expert_ffn_pallas(
             x, wu, wd, jnp.asarray(tg), gated=True))
@@ -171,17 +179,15 @@ class TestFusedKernelParity:
         d, fe, shards = 8, 24, 2
         fs = fe // shards
         x = jnp.asarray(rng.normal(size=(capacity, d)), jnp.float32)
-        wu = jnp.asarray(rng.normal(size=(3, d, 2 * fe)) * 0.2,
+        wu = jnp.asarray(rng.normal(size=(3, d, 2, fe)) * 0.2,
                          jnp.float32)
         wd = jnp.asarray(rng.normal(size=(3, fe, d)) * 0.2, jnp.float32)
         full = np.asarray(fused_expert_ffn_pallas(
             x, wu, wd, jnp.asarray(tg), gated=True))
         partial = np.zeros_like(full)
         for s in range(shards):
-            # gate/up halves are each fe wide: take shard s of both
-            wu_s = jnp.concatenate(
-                [wu[:, :, s * fs:(s + 1) * fs],
-                 wu[:, :, fe + s * fs:fe + (s + 1) * fs]], axis=-1)
+            # gate and up are each fe wide: take shard s of both
+            wu_s = wu[..., s * fs:(s + 1) * fs]
             wd_s = wd[:, s * fs:(s + 1) * fs, :]
             partial += np.asarray(fused_expert_ffn_pallas(
                 x, wu_s, wd_s, jnp.asarray(tg), gated=True))
@@ -195,7 +201,7 @@ class TestFusedKernelParity:
         d, fe, s_loc, tile = 8, 12, 6, 4
         capacity = 6 * tile
         x = jnp.asarray(rng.normal(size=(capacity, d)), jnp.float32)
-        wu = np.asarray(rng.normal(size=(s_loc, d, 2 * fe)) * 0.2,
+        wu = np.asarray(rng.normal(size=(s_loc, d, 2, fe)) * 0.2,
                         np.float32)
         wd = np.asarray(rng.normal(size=(s_loc, fe, d)) * 0.2,
                         np.float32)
@@ -322,6 +328,125 @@ class TestOpsInterpretPerCall:
         want = ref.grouped_matmul_ref(np.asarray(x), np.asarray(w),
                                       np.asarray(tg))
         np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+
+
+def _per_layer_single_dot(x, wu, wd, tg, *, gated, tile_k_up,
+                          tile_k_dn):
+    """One layer's expert FFN as the kernel computed it from a per-layer
+    [S, d, n_up*fe] up operand: one dot over all n_up*fe columns per up
+    k-tile (the two-pass Pallas kernel with the fused kernel's tiles),
+    the fp32 hidden cast once, gated in fp32 (``_activate``), then the
+    down k-tiles.  wu: [S, d, n_up, fe] as stored."""
+    s, d, n_up, fe = wu.shape
+    h = grouped_ffn_pallas(x, wu.reshape(s, d, n_up * fe), tg,
+                           tile_k=tile_k_up, tile_f=n_up * fe)
+    return grouped_ffn_pallas(_activate(h, fe, gated), wd, tg,
+                              tile_k=tile_k_dn, tile_f=d)
+
+
+# token-tile layouts build_pair_buffer emits: every tile live, live
+# tiles then dead ones, capacity-dropped rows, and no local pair at all
+_LAYOUTS = {
+    "all_live": [0, 1, 1, 3, 4],
+    "trailing_dead": [1, 4, -1, -1, -1],
+    "dead_tiles": None,              # drawn by _build
+    "all_dead": [-1, -1, -1, -1],
+}
+
+
+class TestStackedOperand:
+    """The kernel reads the layer-stacked [L, S, d, n_up, fe] /
+    [L, S, fe, d] weights in place at a scalar-prefetched layer index;
+    each hidden column sums the same products over d as the per-layer
+    [S, d, n_up*fe] single dot did, so the output is bitwise the
+    same."""
+
+    @pytest.mark.parametrize("layout", list(_LAYOUTS))
+    @pytest.mark.parametrize("gated", [True, False])
+    @pytest.mark.parametrize("fe", [16, 256])
+    def test_stacked_equals_per_layer_single_dot(self, fe, gated, layout):
+        """fe=256 takes the packed gate/up words apart as the chip does
+        (whole 128-lane blocks); fe=16 indexes the block."""
+        rng = np.random.default_rng(11)
+        n_layers, s_loc, d, tile = 3, 5, 32, 4
+        n_up = 2 if gated else 1
+        tg = _LAYOUTS[layout]
+        if tg is None:
+            _, _, tg, _, _ = _build(rng, 7, 2, s_loc, tile,
+                                    short_capacity=True)
+        tg = jnp.asarray(tg, jnp.int32)
+        x = jnp.asarray(rng.normal(size=(len(tg) * tile, d)),
+                        jnp.bfloat16)
+        wu = jnp.asarray(rng.normal(size=(n_layers, s_loc, d, n_up, fe))
+                         * 0.2, jnp.bfloat16)
+        wd = jnp.asarray(rng.normal(size=(n_layers, s_loc, fe, d)) * 0.2,
+                         jnp.bfloat16)
+        tiles = dict(tile_k_up=16, tile_k_dn=8)
+        for layer in range(n_layers):
+            got = fused_expert_ffn_pallas(x, wu, wd, tg, layer,
+                                          gated=gated, **tiles)
+            want = _per_layer_single_dot(x, wu[layer], wd[layer], tg,
+                                         gated=gated, **tiles)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+            assert not np.asarray(got)[np.repeat(tg, tile) < 0].any()
+
+    @pytest.mark.parametrize("algo", ["metro", "eplb"])
+    def test_apply_lm_matches_per_layer_form(self, algo, monkeypatch):
+        """Prefill and decode through ``apply_lm`` with the fused kernel
+        reading the stacks give the tokens and logits of the per-layer
+        form, where each MoE layer's weights are sliced from the stack
+        before the kernel."""
+        from repro.configs import get_config
+        from repro.core import build_placement, slots_for_ratio
+        from repro.models import init_lm
+        from repro.models import lm as LM
+        from repro.models import moe as MOE
+        from repro.sharding.policy import make_dist
+
+        cfg = get_config("qwen3-30b-a3b").reduced()
+        ep = 2
+        spd = slots_for_ratio(cfg.num_experts, ep, 1.25)
+        dist = make_dist(None, ep_size=ep, slots_per_device=spd)
+        placement = build_placement(cfg.num_experts, ep, spd)
+        params = init_lm(cfg, jax.random.PRNGKey(0), dist,
+                         replica_expert=placement.replica_expert,
+                         dtype=jnp.bfloat16)
+        routing = LM.build_lm_routing(cfg, placement)
+        b, n, steps = 3, 6, 3
+        prompts = jax.random.randint(jax.random.PRNGKey(1), (b, n), 0,
+                                     cfg.vocab_size)
+
+        def serve():
+            kw = dict(routing=routing, moe_impl="fused", algo=algo)
+            cache = LM.init_cache(cfg, dist, b, n + steps)
+            logits, cache, _ = LM.apply_lm(
+                cfg, dist, params, tokens=prompts, cache=cache,
+                mode="prefill", **kw)
+            outs = [logits]
+            tok = jnp.argmax(logits[:, -1:], axis=-1)
+            for t in range(steps):
+                logits, cache, _ = LM.apply_lm(
+                    cfg, dist, params, tokens=tok,
+                    pos=jnp.full((b,), n + t, jnp.int32), cache=cache,
+                    mode="decode", **kw)
+                outs.append(logits)
+                tok = jnp.argmax(logits[:, -1:], axis=-1)
+            return [np.asarray(o, np.float32) for o in outs]
+
+        stacked = serve()
+        moe_ffn = MOE.moe_ffn
+
+        def per_layer(cfg, dist, p, *a, layer=None, **kw):
+            if layer is not None:
+                p = dict(p, w_up=p["w_up"][layer],
+                         w_down=p["w_down"][layer])
+            return moe_ffn(cfg, dist, p, *a, **kw)
+
+        monkeypatch.setattr(MOE, "moe_ffn", per_layer)
+        for got, want in zip(stacked, serve()):
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
 
 
 @pytest.mark.slow

@@ -12,10 +12,12 @@ topology is described inside a fixture, and the tests skip when it
 cannot be.  Keep every such test in this one file.
 """
 import dataclasses
+import re
 import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -27,6 +29,7 @@ from repro.kernels.metro_route import metro_route_pallas
 from repro.kernels.moe_ffn import (fused_expert_ffn_paged_pallas,
                                    fused_expert_ffn_pallas,
                                    grouped_ffn_pallas)
+from repro.launch.steps import StepConfig, make_serve_step
 from repro.models import init_lm
 from repro.models import lm as LM
 from repro.serving.executor import Executor
@@ -107,7 +110,7 @@ def test_fused_expert_ffn(one_chip):
     text = _compile(
         lambda x, wu, wd, tg: fused_expert_ffn_pallas(
             x, wu, wd, tg, gated=True, interpret=False),
-        s((CAP, D), jnp.bfloat16), s((SLOTS, D, 2 * FE), jnp.bfloat16),
+        s((CAP, D), jnp.bfloat16), s((SLOTS, D, 2, FE), jnp.bfloat16),
         s((SLOTS, FE, D), jnp.bfloat16), s((CAP // TILE,)))
     assert _has_kernel(text)
 
@@ -195,3 +198,133 @@ def test_kernel_decode_step_compiles_kernels(one_chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = step.lower(*args).compile().as_text()
     assert chip_smoke.compiled_kernels(text) == set(chip_smoke.KERNELS)
+
+
+# ----------------------------------------------------------------------
+# the serve step reads the expert weights where they are stored
+# ----------------------------------------------------------------------
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = ")
+_KERNEL = "fused_expert_ffn_pallas"
+
+
+def _hlo(text: str):
+    """Optimized HLO text -> ({computation: {name: (type, op, operands,
+    line)}}, name of the entry computation, computations that fusions
+    and reducers call)."""
+    comps, entry, called, cur = {}, None, set(), None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), {})
+            entry = head.group(2) if head.group(1) else entry
+            continue
+        m = _INSTR.match(line)
+        if cur is None or not m:
+            continue
+        rest = line[m.end():]
+        if rest.startswith("("):                 # tuple-typed result
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            typ, rest = rest[:i + 1], rest[i + 2:]
+        else:
+            typ, _, rest = rest.partition(" ")
+        op, _, rest = rest.partition("(")
+        depth, i = 1, 0
+        while depth:
+            depth += {"(": 1, ")": -1}.get(rest[i], 0)
+            i += 1
+        operands = re.findall(r"%([\w.\-]+)", rest[:i - 1])
+        cur[m.group(1)] = (typ, op, operands, line)
+        for key in ("calls", "to_apply"):
+            called.update(re.findall(key + r"=%([\w.\-]+)", line))
+    return comps, entry, called
+
+
+def _trace_to_entry(comps, entry, comp, name, index=None):
+    """Follow one value back through bitcasts, tuples and loop
+    boundaries; returns the entry parameter it is, or the first
+    instruction that makes it (which is then a copy of some kind)."""
+    typ, op, operands, line = comps[comp][name]
+    if op == "bitcast":
+        return _trace_to_entry(comps, entry, comp, operands[0], index)
+    if op == "get-tuple-element":
+        i = int(re.search(r"index=(\d+)", line).group(1))
+        return _trace_to_entry(comps, entry, comp, operands[0], i)
+    if op == "tuple":
+        return _trace_to_entry(comps, entry, comp, operands[index])
+    if op == "while":
+        return _trace_to_entry(comps, entry, comp, operands[0], index)
+    if op == "parameter" and comp == entry:
+        return name
+    if op == "parameter":
+        # a loop body's parameter: what the loop that runs it was given
+        for outer, instrs in comps.items():
+            for wname, (_, wop, wargs, wline) in instrs.items():
+                if wop == "while" and f"body=%{comp}" in wline:
+                    return _trace_to_entry(comps, entry, outer, wargs[0],
+                                           index)
+    return f"{op} {name}"
+
+
+def _cell_serve_step_hlo(one_chip, monkeypatch):
+    """The static cell's serve step (qwen3-30b-a3b at published widths,
+    5 layers, a 4-rank virtual EP group at 1.25x: 160 slots, batch 32,
+    KV cache 1024, METRO, the fused kernel) compiled for a v5e."""
+    cfg = dataclasses.replace(CFG, num_layers=5)
+    ep = 4
+    spd = slots_for_ratio(cfg.num_experts, ep, 1.25)
+    dist = make_dist(None, ep_size=ep, slots_per_device=spd)
+    placement = build_placement(cfg.num_experts, ep, spd)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda k: init_lm(
+        cfg, k, dist, replica_expert=placement.replica_expert,
+        dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: LM.init_cache(cfg, dist, B, 1024)))
+    routing = on_chip(jax.eval_shape(
+        lambda: LM.build_lm_routing(cfg, placement)))
+    step = make_serve_step(StepConfig(cfg=cfg, dist=dist,
+                                      algo_decode="metro",
+                                      moe_impl="fused"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jax.jit(step, donate_argnums=(3,)).lower(
+        params, _shape(one_chip, (B, 1)), _shape(one_chip, (B,)), cache,
+        routing).compile().as_text()
+
+
+def test_serve_step_reads_expert_stacks_in_place(one_chip, monkeypatch):
+    """No copy of expert-weight size in the decode step: nothing but the
+    entry parameters, the loop's plumbing and the fused kernel itself
+    makes a bf16 array as large as one layer's ``w_down``, and the
+    kernel's weight operands are the entry parameters themselves (no
+    per-layer slice, no relayout of ``w_up``)."""
+    comps, entry, called = _hlo(_cell_serve_step_hlo(one_chip,
+                                                     monkeypatch))
+    one_layer = SLOTS * FE * D
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast"}
+    large, kernels = [], []
+    for comp, instrs in comps.items():
+        if comp in called:              # inside a fusion or a reducer
+            continue
+        for name, (typ, op, operands, line) in instrs.items():
+            if op == "custom-call" and name.startswith(_KERNEL):
+                kernels.append((comp, name, operands))
+                continue
+            sizes = [int(np.prod([int(v) for v in dims.split(",") if v]))
+                     for dims in re.findall(r"bf16\[([\d,]*)\]", typ)]
+            if op not in plumbing and any(n >= one_layer for n in sizes):
+                large.append(f"{op} {name} {typ}")
+    assert not large, large
+    assert len(kernels) == 1, kernels
+    comp, _, operands = kernels[0]
+    weights = [_trace_to_entry(comps, entry, comp, w)
+               for w in operands[-2:]]
+    assert ["w_up" in weights[0], "w_down" in weights[1]] == [True, True], \
+        weights
+    assert all(comps[entry][w][1] == "parameter" for w in weights), weights
