@@ -24,9 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import serve
-from bench import weights as W
-from bench.sizes import sizes
+from bench import arch, serve
 
 
 @dataclasses.dataclass
@@ -42,22 +40,6 @@ def prompts(mix: dict, seed: int, index: int, vocab: int) -> np.ndarray:
     rng = np.random.default_rng([int(seed), int(index)])
     return rng.integers(0, vocab, (mix["batch"], mix["prompt"])).astype(
         np.int32)
-
-
-def step_config(cfg: dict):
-    """The launch path's ``StepConfig`` for the configuration's
-    deployment, and its placement."""
-    from repro.launch.steps import StepConfig
-    from repro.sharding.policy import make_dist
-    dep = cfg["deployment"]
-    placement, spd = serve.placement_for(cfg)
-    dist = make_dist(None, ep_size=dep["ep_size"], slots_per_device=spd)
-    sc = StepConfig(cfg=serve.model_config(cfg), dist=dist,
-                    algo_decode=dep["decode_algo"],
-                    algo_train=dep["prefill_algo"],
-                    moe_impl=cfg["engine"]["moe_impl"],
-                    replication_ratio=dep["replication_ratio"])
-    return sc, placement
 
 
 _PROGRAMS: dict = {}
@@ -97,15 +79,17 @@ def _step_programs(sc):
 
 class Batches:
     """The cell's model over weights drawn from the seed, with its two
-    step programs, run one static batch at a time."""
+    step programs, run one static batch at a time.  The model, its step
+    configuration and its weights come from the configuration's
+    architecture module (``arch``)."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int):
         from repro.models import lm as LM
         self.cfg, self.mix, self.seed = cfg, mix, seed
-        self.s = sizes(cfg)
-        sc, placement = step_config(cfg)
-        self.params = serve.program_params(
-            W.root_key(seed), self.s, jnp.asarray(placement.replica_expert))
+        self.arch = arch.of(cfg)
+        self.s = self.arch.sizes(cfg)
+        sc, placement = self.arch.step_config(cfg)
+        self.params = self.arch.program_params(cfg, seed, placement)
         self.routing = LM.build_lm_routing(sc.cfg, placement)
         self.prefill, self.decode, self._cache = step_programs(sc)
         b, n = mix["batch"], mix["prompt"]

@@ -2,7 +2,8 @@
 the cell's traffic through the engine with its known fault held off
 (``bench.witness``), or one static batch through the launch steps (a
 cell whose mix is ``static``; ``--seconds`` is then unused), then the
-float32 reference and the fp8 control over a sample of the finished
+float32 reference and the fp8 control of the configuration's
+architecture module (``bench/arch/``) over a sample of the finished
 requests, drawn as a run draws it.
 
   python3 bench/readings.py --workload <cell> --seconds <s> \\
@@ -35,9 +36,8 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import numpy as np  # noqa: E402
 
-from bench import reference, serve, traffic  # noqa: E402
+from bench import arch, serve, traffic  # noqa: E402
 from bench import run as R  # noqa: E402
-from bench.sizes import sizes  # noqa: E402
 from bench.witness import admission_one_short  # noqa: E402
 
 
@@ -50,10 +50,12 @@ def summary(gaps) -> dict:
 def one_seed(cfg, mix, seed: int, seconds: float, score: int,
              fn_cache) -> dict:
     t = time.perf_counter()
+    family = arch.of(cfg)
+    s = family.sizes(cfg)
     eng = serve.build_engine(cfg, mix, seed, fn_cache=fn_cache)
     with admission_one_short():
         serve.warm_up(eng, cfg, mix)
-        reqs = traffic.generate(mix, seed, seconds, sizes(cfg).vocab)
+        reqs = traffic.generate(mix, seed, seconds, s.vocab)
         tracked, live, t0, t1, _ = R.drive(eng, mix, reqs, seconds)
         R.drain(eng, mix, reqs, live, t1)
     order = R.sample_order(tracked, seed)
@@ -62,10 +64,10 @@ def one_seed(cfg, mix, seed: int, seconds: float, score: int,
     scored = order[:max(score, n_check)]
     del eng, live
     gc.collect()
-    pad = reference.pad_len(serve.max_len_for(cfg, mix))
+    pad = family.pad_len(serve.max_len_for(cfg, mix))
     seqs = [(c.req.prompt, np.asarray(c.obj.generated, np.int32))
             for c in scored]
-    res = reference.served_gaps(sizes(cfg), seed, seqs, pad, control=True)
+    res = family.served_gaps(s, seed, seqs, pad, control=True)
     return {"seed": seed, "requests": len(tracked), "finished": len(order),
             "checked": n_check, "tokens": int(sum(served[:n_check])),
             "program": summary([r["gap"] for r in res[:n_check]]),
@@ -87,18 +89,18 @@ def one_seed_static(cfg, mix, seed: int) -> dict:
     from bench import batch
     t = time.perf_counter()
     prog = batch.Batches(cfg, mix, seed)
+    family, s = prog.arch, prog.s
     sent = [prog.send(0)]
     jax.block_until_ready(sent[0].out[-1])
     served = prog.served(sent[0])
     pick = batch.sample(sent, seed, R.check_of(cfg, mix)["requests"])
     del prog, sent
     gc.collect()
-    vocab = sizes(cfg).vocab
-    seqs = [(batch.prompts(mix, seed, i, vocab)[r], served[r])
+    seqs = [(batch.prompts(mix, seed, i, s.vocab)[r], served[r])
             for i, r in pick]
-    res = reference.served_gaps(
-        sizes(cfg), seed, seqs,
-        reference.pad_len(mix["prompt"] + mix["output"]), control=True)
+    res = family.served_gaps(
+        s, seed, seqs, family.pad_len(mix["prompt"] + mix["output"]),
+        control=True)
     return {"seed": seed, "checked": len(seqs),
             "tokens": int(sum(len(o) for _, o in seqs)),
             "program": summary([r["gap"] for r in res]),

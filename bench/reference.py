@@ -1,4 +1,6 @@
-"""Plain float32 reference of the served models, and its fp8 control.
+"""Plain float32 reference of the Qwen family's models, and its fp8
+control; bound by ``bench/arch/qwen3_moe.py``, through which the
+harness reaches it.
 
 Written from the published architecture (Qwen3-MoE / Qwen2-MoE
 decoders), in ``jax.numpy`` under ``default_matmul_precision("highest")``,

@@ -28,14 +28,27 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
+from bench import arch, serve  # noqa: E402
 from bench import run as R  # noqa: E402
-from bench import serve  # noqa: E402
-from bench import weights as W  # noqa: E402
-from bench.sizes import sizes  # noqa: E402
 
 HBM_BYTES = 15.75e9         # what XLA lets one v5e program use
 KERNELS = ("fused_expert_ffn_pallas", "flash_decode_paged",
            "metro_route_pallas")
+
+
+def _on_chip(tree, one_chip):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), tree)
+
+
+def weights(cfg, placement, one_chip):
+    """The weights' generator of the configuration's architecture module
+    (seed 0, as a program with no arguments placed on ``one_chip``) and
+    the shapes of the weights it draws."""
+    family = arch.of(cfg)
+    draw = jax.jit(lambda: family.program_params(cfg, 0, placement),
+                   out_shardings=one_chip)
+    return ("weights", draw, ()), _on_chip(jax.eval_shape(draw), one_chip)
 
 
 def programs(cfg, mix, one_chip):
@@ -45,30 +58,21 @@ def programs(cfg, mix, one_chip):
     from repro.models import lm as LM
     from repro.serving.executor import Executor, _regather_slots
     from repro.serving.kv import pages_for
-    from repro.sharding.policy import make_dist
 
     if mix["loop"] == "static":
         return static_programs(cfg, mix, one_chip)
-    mcfg = serve.model_config(cfg)
+    sc, placement = arch.of(cfg).step_config(cfg)
+    mcfg, dist = sc.cfg, sc.dist
     ecfg = serve.engine_config(cfg, mix)
-    placement, spd = serve.placement_for(cfg)
-    ep = cfg["deployment"]["ep_size"]
-    dist = make_dist(None, ep_size=ep, slots_per_device=spd)
-    s = sizes(cfg)
-    on_chip = lambda tree: jax.tree.map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=one_chip), tree)
-    ids = jnp.asarray(placement.replica_expert)
-    params = on_chip(jax.eval_shape(
-        lambda: serve._program_params(W.root_key(0), s, ids)))
+    gen, params = weights(cfg, placement, one_chip)
     pmax = pages_for(ecfg.max_len, ecfg.page_size)
-    cache = on_chip(jax.eval_shape(lambda: LM.init_paged_cache(
+    cache = _on_chip(jax.eval_shape(lambda: LM.init_paged_cache(
         mcfg, dist, ecfg.num_pages or ecfg.max_batch * pmax,
-        ecfg.page_size, ecfg.max_batch)))
-    width = max(min(dist.num_slots - s.experts + 1, 2 * ep),
-                placement.max_replicas)
-    routing = on_chip(jax.eval_shape(
-        lambda: LM.build_lm_routing(mcfg, placement, width)))
+        ecfg.page_size, ecfg.max_batch)), one_chip)
+    width = max(min(dist.num_slots - placement.num_experts + 1,
+                    2 * dist.ep_size), placement.max_replicas)
+    routing = _on_chip(jax.eval_shape(
+        lambda: LM.build_lm_routing(mcfg, placement, width)), one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(      # noqa: E731
         shape, jnp.int32, sharding=one_chip)
     b, c = ecfg.max_batch, ecfg.prefill_chunk
@@ -78,9 +82,11 @@ def programs(cfg, mix, one_chip):
 
     def chunk(bp):
         return (i32(bp, c), i32(bp), i32(bp), i32(bp), i32(bp, pmax))
-    key = on_chip(jax.eval_shape(lambda: W.root_key(0)))
     shapes = serve.step_shapes(cfg, mix)
-    return ([("weights", serve.program_params, (key, s, ids))]
+    # the rebalance regathers every expert stack alike: one is compiled
+    w_up = next(v for path, v in jax.tree_util.tree_leaves_with_path(
+        params["blocks"]) if path[-1].key == "w_up")
+    return ([gen]
             + [(f"chunk({bp})", Executor.chunk_fn(exe, bp),
                 (params, *chunk(bp), cache, routing))
                for bp in shapes["chunk"]]
@@ -90,8 +96,7 @@ def programs(cfg, mix, one_chip):
             + [(f"decode({b})", Executor.decode_fn(exe, b),
                 (params, *dec, cache, routing))]
             + [("regather w_up", _regather_slots,
-                (params["blocks"]["l0"]["moe"]["w_up"],
-                 i32(dist.num_slots)))])
+                (w_up, i32(dist.num_slots)))])
 
 
 def static_programs(cfg, mix, one_chip):
@@ -99,23 +104,17 @@ def static_programs(cfg, mix, one_chip):
     the prefill step and the serve step)."""
     from bench import batch
     from repro.models import lm as LM
-    s = sizes(cfg)
-    sc, placement = batch.step_config(cfg)
-    on_chip = lambda tree: jax.tree.map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=one_chip), tree)
-    ids = jnp.asarray(placement.replica_expert)
-    params = on_chip(jax.eval_shape(
-        lambda: serve._program_params(W.root_key(0), s, ids)))
-    routing = on_chip(jax.eval_shape(
-        lambda: LM.build_lm_routing(sc.cfg, placement)))
+    sc, placement = arch.of(cfg).step_config(cfg)
+    gen, params = weights(cfg, placement, one_chip)
+    routing = _on_chip(jax.eval_shape(
+        lambda: LM.build_lm_routing(sc.cfg, placement)), one_chip)
     b, n = mix["batch"], mix["prompt"]
     prefill, decode, new_cache = batch.step_programs(sc)
-    cache = on_chip(jax.eval_shape(lambda: new_cache(b, n + mix["output"])))
+    cache = _on_chip(jax.eval_shape(
+        lambda: new_cache(b, n + mix["output"])), one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(      # noqa: E731
         shape, jnp.int32, sharding=one_chip)
-    key = on_chip(jax.eval_shape(lambda: W.root_key(0)))
-    return [("weights", serve.program_params, (key, s, ids)),
+    return [gen,
             (f"prefill({b},{n})", prefill, (params, i32(b, n), cache,
                                             routing)),
             (f"decode({b})", decode, (params, i32(b), i32(b), cache,
@@ -138,7 +137,9 @@ def main(argv) -> int:
     ok = True
     for name in argv:
         _, _, cfg, mix = R.load_cell(name)
-        print(f"{name}: max_len {serve.max_len_for(cfg, mix)}", flush=True)
+        max_len = (mix["prompt"] + mix["output"] if mix["loop"] == "static"
+                   else serve.max_len_for(cfg, mix))
+        print(f"{name}: max_len {max_len}", flush=True)
         for label, fn, args in programs(cfg, mix, one_chip):
             try:
                 compiled = fn.lower(*args).compile()

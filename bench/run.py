@@ -17,11 +17,11 @@ one is done (``bench/batch.py``).  ``--trace 1`` records the window with the pro
 reports the per-layer metrics instead of the end-to-end ones.
 
 Then the answers are checked: memory's peak is read, the program is
-freed, and the float32 reference (``bench/reference.py``) scores a
-sample of the finished requests drawn from the seed.  The numbers
-compared are printed beside their limits as the last lines on standard
-error and under ``checks``, the last key of the JSON line that ends
-standard output.
+freed, and the float32 reference of the configuration's architecture
+module (``bench/arch/<model_type>.py``) scores a sample of the finished
+requests drawn from the seed.  The numbers compared are printed beside
+their limits as the last lines on standard error and under ``checks``,
+the last key of the JSON line that ends standard output.
 
 Without a TPU, or with fewer chips than the cell asks for, the run
 exits 1 and prints no result.
@@ -100,7 +100,9 @@ class Tracked:
 
 
 class Run:
-    """What one run recorded; the metric readers read it."""
+    """What one run recorded; the metric readers read it.  ``arch`` is
+    the configuration's architecture module (``bench/arch/``) and
+    ``sizes`` the sizes it gives."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -238,11 +240,12 @@ def check_answers(cfg, seed, seqs, pad_to: int) -> dict:
     over ``seqs`` [(prompt, served tokens)], each padded to ``pad_to``:
     its mean (the number compared) and its widest (printed).  The widest
     is set by single near-ties and reads alike for the program and for
-    its fp8 control; the mean separates them (PERF.md section 6)."""
-    from bench import reference
-    from bench.sizes import sizes
-    res = reference.served_gaps(sizes(cfg), seed, seqs,
-                                reference.pad_len(pad_to))
+    its fp8 control; the mean separates them (PERF.md section 6).  The
+    reference is the configuration's architecture module's."""
+    from bench import arch
+    family = arch.of(cfg)
+    res = family.served_gaps(family.sizes(cfg), seed, seqs,
+                             family.pad_len(pad_to))
     gaps = np.concatenate([r["gap"] for r in res])
     return {"mean_logit_gap": float(gaps.mean()),
             "max_logit_gap": float(gaps.max()), "tokens": int(gaps.size),
@@ -261,10 +264,10 @@ def measure_served(cfg, mix, seed, seconds, trace, t_start, dev):
     sampled [(prompt, served tokens)] for the check, the length they
     are padded to); the engine is gone when it returns."""
     import jax
-    from bench import serve, traffic, work
-    from bench.sizes import sizes
+    from bench import arch, serve, traffic, work
 
-    s = sizes(cfg)
+    family = arch.of(cfg)
+    s = family.sizes(cfg)
     eng = serve.build_engine(cfg, mix, seed)
     serve.warm_up(eng, cfg, mix)
     requests = traffic.generate(mix, seed, seconds, s.vocab)
@@ -289,8 +292,9 @@ def measure_served(cfg, mix, seed, seconds, trace, t_start, dev):
         t.obj.generated) < 0 or max(t.obj.generated) >= s.vocab]
     ok = [t for t in reqs if t not in failed]
     run = Run(
-        sizes=s, cfg=cfg, mix=mix, window_s=t1 - t0, setup_s=t0 - t_start,
-        requests=ok, attempted=len(reqs), tokens=None,
+        arch=family, sizes=s, cfg=cfg, mix=mix, window_s=t1 - t0,
+        setup_s=t0 - t_start, requests=ok, attempted=len(reqs),
+        tokens=None,
         steps=rec.steps, step_parts=[serve.step_stats(r)
                                      for r in rec.steps],
         step_seconds=sum(r.wall for r in rec.steps),
@@ -335,8 +339,9 @@ def measure_static(cfg, mix, seed, seconds, trace, t_start, dev):
     rows = mix["batch"] * len(sent)
     steps = batch.steps(mix, sent)
     run = Run(
-        sizes=s, cfg=cfg, mix=mix, window_s=t1 - t0, setup_s=t0 - t_start,
-        requests=[], attempted=rows, tokens=served[0].size * len(sent),
+        arch=prog.arch, sizes=s, cfg=cfg, mix=mix, window_s=t1 - t0,
+        setup_s=t0 - t_start, requests=[], attempted=rows,
+        tokens=served[0].size * len(sent),
         steps=steps, step_parts=[serve.step_stats(r) for r in steps],
         step_seconds=t1 - t0,
         peaks=work.peaks(dev.device_kind) if dev.platform == "tpu"

@@ -1,8 +1,9 @@
-"""The program under test, seen from the benchmark: a configuration file
-turned into a ``ServingEngine`` over the seeded weights, and the
+"""The program's serving engine, seen from the benchmark: a configuration
+file turned into a ``ServingEngine`` over the seeded weights, and the
 instrumentation the benchmark wraps around the engine's layer calls.
 
-This is the one module of the benchmark that imports ``repro``.
+The model, its ``Dist`` and its weights come from the configuration's
+architecture module (``bench/arch/``).
 """
 from __future__ import annotations
 
@@ -11,33 +12,11 @@ import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
-from bench.sizes import Sizes, sizes
-from repro.configs.base import ModelConfig
-from repro.core import build_placement, slots_for_ratio
+from bench import arch
 from repro.serving import EngineConfig, ServingEngine
 from repro.serving.kv import pages_for
-from repro.sharding.policy import make_dist
-
-
-def model_config(cfg: dict) -> ModelConfig:
-    m, s = cfg["model"], sizes(cfg)
-    assert not s.f_shared or s.f_shared % s.fe == 0, \
-        "the engine builds the shared expert as whole expert widths"
-    return ModelConfig(
-        name=cfg["name"], family="moe", num_layers=s.layers, d_model=s.d,
-        num_heads=s.heads, num_kv_heads=s.kv_heads, head_dim=s.head_dim,
-        d_ff=m["intermediate_size"], vocab_size=s.vocab,
-        qk_norm=s.qk_norm, rope_theta=s.rope_theta,
-        max_seq_len=m["max_position_embeddings"],
-        num_experts=s.experts, num_experts_per_tok=s.top_k,
-        num_shared_experts=s.f_shared // s.fe, d_ff_expert=s.fe,
-        norm_topk_prob=s.norm_topk,
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        gated_mlp=m["hidden_act"] == "silu")
 
 
 def max_len_for(cfg: dict, traffic: dict) -> int:
@@ -57,59 +36,19 @@ def engine_config(cfg: dict, traffic: dict) -> EngineConfig:
         **e)
 
 
-def placement_for(cfg: dict):
-    s, dep = sizes(cfg), cfg["deployment"]
-    spd = slots_for_ratio(s.experts, dep["ep_size"],
-                          dep["replication_ratio"])
-    return build_placement(s.experts, dep["ep_size"], spd), spd
-
-
-def _program_params(root, s: Sizes, replica_expert):
-    """The engine's parameter tree, drawn on the device in one program:
-    layers one after another (``lax.map``), each slot from its expert's
-    key, every tensor in the served dtype."""
-    ids = jnp.arange(s.layers)
-
-    def layer(li):
-        t = {n: W.tensor(root, s, n, li) for n in W.layer_names(s)}
-        attn = {"wq": t["wq"], "wk": t["wk"], "wv": t["wv"], "wo": t["wo"]}
-        if s.qk_norm:
-            attn.update(q_norm=t["q_norm"], k_norm=t["k_norm"])
-        moe = {"w_router": t["router"],
-               "w_up": W.experts(root, s, "w_up", li, replica_expert),
-               "w_down": W.experts(root, s, "w_down", li, replica_expert)}
-        if s.f_shared:
-            moe.update(shared_up=t["shared_up"],
-                       shared_down=t["shared_down"])
-        return {"norm1": {"scale": t["norm1"]}, "attn": attn,
-                "norm2": {"scale": t["norm2"]}, "moe": moe}
-
-    return {"embed": W.tensor(root, s, "embed"),
-            "unembed": W.tensor(root, s, "unembed"),
-            "final_norm": {"scale": W.tensor(root, s, "final_norm")},
-            "blocks": {"l0": jax.lax.map(layer, ids)}}
-
-
-program_params = jax.jit(_program_params, static_argnums=(1,))
-
-
 def build_engine(cfg: dict, traffic: dict, seed: int,
                  fn_cache=None) -> ServingEngine:
     """The cell's engine over weights drawn from ``seed``, built as
-    ``repro.launch.serve.build_engine`` builds one (virtual EP group,
-    EPLB's initial placement), with the weights handed in.  Engines of
-    one configuration may share ``fn_cache`` (their step programs)."""
-    mcfg = model_config(cfg)
-    assert mcfg.pattern_period == 1 and mcfg.layer_kinds() == [
-        ("attn_full", "moe")], "the weights' layout is one MoE layer kind"
-    placement, spd = placement_for(cfg)
-    dist = make_dist(None, ep_size=cfg["deployment"]["ep_size"],
-                     slots_per_device=spd)
-    params = program_params(W.root_key(seed), sizes(cfg),
-                            jnp.asarray(placement.replica_expert))
+    ``repro.launch.serve.build_engine`` builds one (EPLB's initial
+    placement), with the model, its ``Dist`` and the weights handed in
+    by the configuration's architecture module.  Engines of one
+    configuration may share ``fn_cache`` (their step programs)."""
+    family = arch.of(cfg)
+    sc, placement = family.step_config(cfg)
+    params = family.program_params(cfg, seed, placement)
     jax.block_until_ready(params)
     ecfg = dataclasses.replace(engine_config(cfg, traffic), seed=seed)
-    return ServingEngine(mcfg, dist, params, ecfg, fn_cache=fn_cache)
+    return ServingEngine(sc.cfg, sc.dist, params, ecfg, fn_cache=fn_cache)
 
 
 # ----------------------------------------------------------------------

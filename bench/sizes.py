@@ -1,9 +1,12 @@
-"""A model's sizes, read from the ``model`` and ``architecture`` groups
-of a configuration file (``configs/<config>.json``).
+"""The Qwen family's sizes (Qwen3-MoE, Qwen2-MoE), read from the
+``model`` and ``architecture`` groups of a configuration file
+(``configs/<config>.json``); bound by ``bench/arch/qwen3_moe.py``.
 
 The ``model`` group holds the published ``config.json`` keys, with the
 depth as run; ``architecture`` states the structural facts the run
-uses (query/key norms, q/k/v biases, a shared-expert gate).
+uses (query/key norms, q/k/v biases, a shared-expert gate).  Generic
+code reads only ``vocab``, ``layers``, ``d`` and ``fe`` of a family's
+sizes (``bench/arch/__init__.py``).
 """
 from __future__ import annotations
 

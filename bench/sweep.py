@@ -26,9 +26,8 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import serve, traffic  # noqa: E402
+from bench import arch, serve, traffic  # noqa: E402
 from bench import run as R  # noqa: E402
-from bench.sizes import sizes  # noqa: E402
 from bench.tails import pct  # noqa: E402
 
 
@@ -45,8 +44,8 @@ def one_window(cfg, mix, seed, rate, seconds, drain, fn_cache) -> dict:
     print(f"seed {seed} rate {rate}: memory after warm-up "
           f"{jax.devices()[0].memory_stats()}", file=sys.stderr, flush=True)
     rec = serve.Recorder(eng, spans=False)
-    reqs = traffic.generate(mix, seed, seconds, sizes(cfg).vocab,
-                            rate=rate)
+    reqs = traffic.generate(mix, seed, seconds,
+                            arch.of(cfg).sizes(cfg).vocab, rate=rate)
     tracked, live, t0, t1, late = R.drive(eng, mix, reqs, seconds)
     R.drain(eng, {"loop": "closed"}, [], live, t1, limit=drain)
     adm = rec.admitted

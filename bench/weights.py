@@ -1,5 +1,9 @@
 """Seeded weights: each tensor of the model is a pure function of the
 run's seed, its name, its layer and (for an expert) the expert's id.
+:func:`root_key` and :func:`draw` are that scheme, for every family;
+the tables below them (:func:`shapes`, :func:`stds` and the helpers
+that read them) are the Qwen family's, bound by
+``bench/arch/qwen3_moe.py``.
 
 The served weights are these draws rounded to bfloat16, and the
 reference reads the very same bfloat16 values in float32.  Both sides
@@ -41,6 +45,11 @@ def draw(root, name: str, layer, expert, shape, std: float):
     k = jax.random.fold_in(jax.random.fold_in(k, layer), expert)
     return (jax.random.normal(k, shape, jnp.float32) * std).astype(
         SERVED_DTYPE)
+
+
+# ----------------------------------------------------------------------
+# the Qwen family's tensors
+# ----------------------------------------------------------------------
 
 
 def stds(s: Sizes) -> dict:

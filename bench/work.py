@@ -2,14 +2,16 @@
 peaks.  These are the numerators of the roofline shares and of
 ``step_mfu``: what the algorithm requires, whatever implements it.
 
-All matrices are served in bf16 (2 bytes an element).
+What every family shares is counted here: the routed experts' FFN,
+from the ``d`` and ``fe`` of a family's sizes.  A family's own counts
+(a step's model FLOPs, its decode attention) are in its architecture
+module, ``bench/arch/<model_type>.py``.  All matrices are served in
+bf16 (2 bytes an element).
 """
 from __future__ import annotations
 
 import json
 import os
-
-from bench.sizes import Sizes
 
 BYTES = 2
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -34,7 +36,7 @@ def min_seconds(flops: float, nbytes: float, pk: dict) -> float:
                nbytes / pk["hbm_bytes_per_s"])
 
 
-def expert_ffn(s: Sizes, slot_hist) -> tuple[float, float]:
+def expert_ffn(s, slot_hist) -> tuple[float, float]:
     """One MoE layer's routed-expert FFN over the (token, expert) pairs
     counted per replica slot in ``slot_hist``: (FLOPs, bytes).  Each
     pair costs up, gate and down projections, 6 * d * fe FLOPs; each
@@ -45,43 +47,3 @@ def expert_ffn(s: Sizes, slot_hist) -> tuple[float, float]:
     flops = 6.0 * s.d * s.fe * pairs
     nbytes = active * 3 * s.d * s.fe * BYTES + pairs * 2 * s.d * BYTES
     return flops, nbytes
-
-
-def flash_decode(s: Sizes, positions) -> tuple[float, float]:
-    """One layer's decode attention for live rows writing at
-    ``positions``: each row reads the K and V of its ``p + 1`` context
-    tokens, and its query and output.  (FLOPs, bytes)."""
-    ctx = float(sum(p + 1 for p in positions))
-    rows = len(positions)
-    flops = 4.0 * s.heads * s.head_dim * ctx
-    nbytes = (ctx * s.kv_bytes_per_token_layer
-              + rows * 2 * s.heads * s.head_dim * BYTES)
-    return flops, nbytes
-
-
-def dense_flops_per_token(s: Sizes) -> float:
-    """Matmul FLOPs of one token through one layer, outside attention's
-    scores: the projections, the router, the token's top-k experts and
-    the shared expert."""
-    q, kv = s.heads * s.head_dim, s.kv_heads * s.head_dim
-    params = (s.d * (q + 2 * kv) + q * s.d + s.d * s.experts
-              + s.top_k * 3 * s.d * s.fe + 3 * s.d * s.f_shared)
-    return 2.0 * params
-
-
-def step_flops(s: Sizes, prefill, decode_positions) -> float:
-    """Model FLOPs of one engine step: ``prefill`` is [(start, n)] per
-    chunk row, ``decode_positions`` the position each live decode row
-    writes.  Attention scores count each token's causal context; the LM
-    head counts only rows whose logits are used (decode rows)."""
-    per_tok = dense_flops_per_token(s)
-    attn = 4.0 * s.heads * s.head_dim
-    tokens = ctx = 0.0
-    for start, n in prefill:
-        tokens += n
-        ctx += n * start + n * (n + 1) / 2      # sum of (p + 1)
-    for p in decode_positions:
-        tokens += 1
-        ctx += p + 1
-    head = 2.0 * s.d * s.vocab * len(decode_positions)
-    return s.layers * (tokens * per_tok + attn * ctx) + head

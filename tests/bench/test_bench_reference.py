@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, serve
+from bench import reference
 from bench import weights as W
+from bench.arch import qwen3_moe as Q
 from bench.sizes import reduced, sizes
 from repro.models import lm as LM
 from repro.sharding.policy import make_dist
@@ -47,12 +48,11 @@ def _program_logits(cfg, seed, seqs, algo):
     """The engine's own step program path: ``apply_lm`` in
     ``chunk_prefill`` mode over the prompts, then ``decode`` steps, on
     the paged cache, with the cell's kernels."""
-    s, mcfg = sizes(cfg), serve.model_config(cfg)
-    placement, spd = serve.placement_for(cfg)
+    s, mcfg = sizes(cfg), Q.model_config(cfg)
+    placement, spd = Q.placement_for(cfg)
     ep = cfg["deployment"]["ep_size"]
     dist = make_dist(None, ep_size=ep, slots_per_device=spd)
-    params = serve.program_params(W.root_key(seed), s,
-                                  jnp.asarray(placement.replica_expert))
+    params = Q.program_params(cfg, seed, placement)
     width = max(min(dist.num_slots - s.experts + 1, 2 * ep),
                 placement.max_replicas)
     routing = LM.build_lm_routing(mcfg, placement, width)
@@ -102,9 +102,8 @@ def test_program_weights_are_the_references(name):
     """Every replica slot holds its expert's draw, bit for bit."""
     cfg = _cfg(name)
     s = sizes(cfg)
-    placement, _ = serve.placement_for(cfg)
-    p = serve.program_params(W.root_key(5), s,
-                             jnp.asarray(placement.replica_expert))
+    placement, _ = Q.placement_for(cfg)
+    p = Q.program_params(cfg, 5, placement)
     moe = p["blocks"]["l0"]["moe"]
     for li in range(s.layers):
         ref = W.experts(W.root_key(5), s, "w_up", li,

@@ -1,13 +1,15 @@
-"""Work counts and peaks of the benchmark (bench/work.py), against hand
-counts at published widths; and the traffic generator's fixed multiset
-of sizes (bench/traffic.py)."""
+"""Work counts and peaks of the benchmark (bench/work.py and the Qwen
+family's counts in bench/arch/qwen3_moe.py), against hand counts at
+published widths; and the traffic generator's fixed multiset of sizes
+(bench/traffic.py)."""
 import json
 import os
 
 import numpy as np
 import pytest
 
-from bench import traffic, work
+from bench import arch, traffic, work
+from bench.arch import qwen3_moe as Q
 from bench.sizes import Sizes, sizes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -46,7 +48,7 @@ def test_expert_ffn_hand_count(s, d, fe):
 
 @pytest.mark.parametrize("s,heads,kv", [(QWEN3, 32, 4), (QWEN2, 16, 16)])
 def test_flash_decode_hand_count(s, heads, kv):
-    flops, nbytes = work.flash_decode(s, [0, 99])     # 1 + 100 keys
+    flops, nbytes = Q.flash_decode(s, [0, 99])        # 1 + 100 keys
     assert flops == 101 * heads * 128 * 2 * 2          # q.k and p.v
     assert nbytes == 101 * kv * 128 * 2 * 2 + 2 * (heads * 128 * 2) * 2
 
@@ -58,15 +60,38 @@ def test_step_flops_hand_count_qwen3():
     per_tok = 2 * (proj + d * 128 + 8 * 3 * d * 768)
     attn_ctx = 10 + (101 + 102 + 103 + 104)
     want = L * (5 * per_tok + 4 * 32 * 128 * attn_ctx) + 2 * d * v
-    assert work.step_flops(QWEN3, [(100, 4)], [9]) == want
+    assert Q.step_flops(QWEN3, [(100, 4)], [9]) == want
 
 
 def test_step_flops_counts_shared_expert_qwen2():
     d = 2048
-    one = work.step_flops(QWEN2, [], [0])
+    one = Q.step_flops(QWEN2, [], [0])
     proj = d * 16 * 128 * 2 + 2 * d * 16 * 128
     per_tok = 2 * (proj + d * 60 + 4 * 3 * d * 1408 + 3 * d * 5632)
     assert one == 4 * (per_tok + 4 * 16 * 128) + 2 * d * 151936
+
+
+def test_qwen3_counts_through_the_plugin():
+    """The qwen3 configuration's counts as the harness reaches them
+    (``arch.of`` of the configuration file, its sizes and work counts)
+    equal the hand counts above exactly: a step's model FLOPs, one
+    layer's routed experts and its decode attention."""
+    with open(os.path.join(ROOT, "bench", "configs",
+                           "qwen3-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    family = arch.of(cfg)
+    s = family.sizes(cfg)
+    d, fe, v, L = 2048, 768, 151936, 5
+    proj = d * 32 * 128 + 2 * d * 4 * 128 + 32 * 128 * d
+    per_tok = 2 * (proj + d * 128 + 8 * 3 * d * fe)
+    attn_ctx = 10 + (101 + 102 + 103 + 104)
+    assert family.step_flops(s, [(100, 4)], [9]) == (
+        L * (5 * per_tok + 4 * 32 * 128 * attn_ctx) + 2 * d * v)
+    assert work.expert_ffn(s, [2, 0, 5, 1, 0]) == (
+        8 * (2 * d * fe * 2 + 2 * fe * d),
+        3 * (d * 2 * fe + fe * d) * 2 + 8 * (d + d) * 2)
+    assert family.flash_decode(s, [0, 99]) == (
+        101 * 32 * 128 * 2 * 2, 101 * 4 * 128 * 2 * 2 + 2 * 32 * 128 * 2 * 2)
 
 
 def test_min_seconds_is_the_larger_bound():
